@@ -40,6 +40,7 @@ finding — exhaustiveness is the default, sampling is never silent.
 """
 from __future__ import annotations
 
+import inspect
 import itertools
 import numbers
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -72,6 +73,7 @@ def _is_static_int(v) -> bool:
 
 
 def _closure_values(fn) -> List[Any]:
+    fn = inspect.unwrap(fn)           # Pallas wraps index maps
     vals = list(fn.__defaults__ or ())
     for cell in fn.__closure__ or ():
         try:

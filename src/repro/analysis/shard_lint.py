@@ -33,6 +33,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.extend.core import ClosedJaxpr
 
 from repro.analysis import Finding
 
@@ -106,11 +107,11 @@ def lint_spec_tree(sds_tree: Any, spec_tree: Any,
 
 def _sub_jaxprs(eqn):
     for v in eqn.params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, ClosedJaxpr):
             yield v.jaxpr
         elif isinstance(v, (list, tuple)):
             for u in v:
-                if isinstance(u, jax.core.ClosedJaxpr):
+                if isinstance(u, ClosedJaxpr):
                     yield u.jaxpr
 
 
@@ -121,7 +122,7 @@ def lint_jaxpr(jaxpr: Any, *, subject: str = "",
     bf16 -> f32 upcasts; recursive over scan/while/cond sub-jaxprs. Inner
     (scan body) upcasts execute once per trip, so they dominate — each
     large site is one warning, plus one info total."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     out: List[Finding] = []
     sites: dict = {}                  # shape -> site count

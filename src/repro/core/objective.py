@@ -19,6 +19,12 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+# Link loads are sums of edge weights. On a TPU an f32 GEMM at default
+# precision rounds its operands to bf16 (8 significant bits), so loads above
+# a few hundred would be off by up to 2^-8 relative; the objective and the
+# acceptance tests on it must be exact, so every load GEMM runs at HIGHEST.
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 class MakespanBreakdown(NamedTuple):
     makespan: jnp.ndarray      # scalar
@@ -61,14 +67,16 @@ def link_loads_tree(W: jnp.ndarray, subtree: jnp.ndarray) -> jnp.ndarray:
     S = subtree
     r = W.sum(axis=1)
     c = W.sum(axis=0)
-    cross = jnp.einsum("li,ij,lj->l", S, W, S)
+    cross = jnp.einsum("li,ij,lj->l", S, W, S, precision=_EXACT)
     # arc-based W double-counts undirected edges -> halve
-    return 0.5 * (S @ r + S @ c - 2.0 * cross)
+    return 0.5 * (jnp.matmul(S, r, precision=_EXACT)
+                  + jnp.matmul(S, c, precision=_EXACT) - 2.0 * cross)
 
 
 def link_loads_routing(W: jnp.ndarray, path_incidence: jnp.ndarray) -> jnp.ndarray:
     """comm(l) under a routing oracle: R[i, j, l] fractional incidence. [L]"""
-    return 0.5 * jnp.einsum("ij,ijl->l", W, path_incidence)
+    return 0.5 * jnp.einsum("ij,ijl->l", W, path_incidence,
+                            precision=_EXACT)
 
 
 def makespan_from_parts(comp: jnp.ndarray, comm: jnp.ndarray, F_l: jnp.ndarray,
@@ -130,8 +138,9 @@ def permutation_link_loads(T: jnp.ndarray, subtree: jnp.ndarray,
     counts each undirected edge once, as ``link_loads_tree`` does.
     """
     S_g = jnp.take(subtree, device_to_bin, axis=1)     # [L, D]
-    rc = S_g @ (T.sum(axis=1) + T.sum(axis=0))         # (S@r + S@c), permuted
-    cross = ((S_g @ T) * S_g).sum(axis=1)              # diag(Sg T Sg^T)
+    rc = jnp.matmul(S_g, T.sum(axis=1) + T.sum(axis=0),   # (S@r + S@c),
+                    precision=_EXACT)                      # permuted
+    cross = (jnp.matmul(S_g, T, precision=_EXACT) * S_g).sum(axis=1)
     return 0.5 * (rc - 2.0 * cross)
 
 
@@ -179,7 +188,8 @@ def permutation_link_loads_batch(device_to_bin: jnp.ndarray,
     wq = jnp.broadcast_to(pair_w[None, :], (c, e)).reshape(-1)
     q = jax.ops.segment_sum(wq, (row * n_nodes + lca).reshape(-1),
                             num_segments=c * n_nodes).reshape(c, n_nodes)
-    return ws @ subtree.T - 2.0 * (q @ node_subtree.T)
+    return (jnp.matmul(ws, subtree.T, precision=_EXACT)
+            - 2.0 * jnp.matmul(q, node_subtree.T, precision=_EXACT))
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

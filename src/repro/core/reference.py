@@ -55,11 +55,16 @@ def makespan_ref(part: np.ndarray, g: Graph, topo: TreeTopology,
         comp = comp / np.asarray(speed, dtype=comp.dtype)
     comm = np.zeros(topo.n_links)
     seen = g.senders < g.receivers
-    for u, v, w in zip(g.senders[seen], g.receivers[seen], g.edge_weight[seen]):
-        bu, bv = int(part[u]), int(part[v])
-        if bu == bv:
-            continue
-        for l in tree_path_links(topo, bu, bv):
+    bu, bv = part[g.senders[seen]], part[g.receivers[seen]]
+    cut = bu != bv
+    # every cut edge between the same two bins walks the same path: sum
+    # their weights per bin pair, then walk each distinct pair once
+    pairs, inv = np.unique(np.stack([bu[cut], bv[cut]], axis=1), axis=0,
+                           return_inverse=True)
+    pair_w = np.zeros(len(pairs))
+    np.add.at(pair_w, inv.ravel(), g.edge_weight[seen][cut])
+    for (a, b), w in zip(pairs, pair_w):
+        for l in tree_path_links(topo, int(a), int(b)):
             comm[l] += w
     comm_cost = topo.F_l * comm
     m = max(comp.max(), comm_cost.max() if comm.size else 0.0)

@@ -15,6 +15,11 @@ accumulates into its own output block (``out_accumulate``), which is the
 write-race shape the static verifier proves safe. Operands are lifted to
 3-d ``[*, 1, F]`` so every block spans the second-minor dim (no sublane
 penalty). Padding slots point at row 0 with weight 0.
+
+Scalar-prefetched operands live whole in SMEM (1 MiB on v5e), so the
+wrapper runs the call over chunks of at most ``PREFETCH_SLOTS`` bag slots
+(``lax.map`` over bag chunks): a 4096 x 50 batch would otherwise need
+1.6 MB of ids + weights there.
 """
 from __future__ import annotations
 
@@ -27,6 +32,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.plan import KernelPlan
+
+PREFETCH_SLOTS = 32768      # bag slots per call: 2 x 128 KiB of SMEM
 
 
 def _kernel(ids_ref, w_ref, tbl_ref, out_ref, *, nd: int):
@@ -89,10 +96,16 @@ def gather_combine(table: jnp.ndarray, idx: jnp.ndarray,
     [B, D] weights -> [B, F] without materializing [B, D, F]."""
     v, f = table.shape
     b, d = idx.shape
-    p = plan(b, d, v, f, feat_blk=feat_blk, dtype=table.dtype)
+    chunk = max(1, min(b, PREFETCH_SLOTS // d))
+    n_chunks = -(-b // chunk)
+    p = plan(chunk, d, v, f, feat_blk=feat_blk, dtype=table.dtype)
     f_pad = p.meta["f_pad"]
     tbl = jnp.pad(table, ((0, 0), (0, f_pad - f)))[:, None, :]
-    out = pl.pallas_call(
+    pad = ((0, n_chunks * chunk - b), (0, 0))
+    ids = jnp.pad(idx.astype(jnp.int32), pad).reshape(n_chunks, chunk * d)
+    ws = jnp.pad(weights.astype(jnp.float32), pad).reshape(n_chunks,
+                                                           chunk * d)
+    call = pl.pallas_call(
         functools.partial(_kernel, nd=d),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -102,6 +115,6 @@ def gather_combine(table: jnp.ndarray, idx: jnp.ndarray,
         ),
         out_shape=p.outputs[0],
         interpret=interpret,
-    )(idx.astype(jnp.int32).ravel(),
-      weights.astype(jnp.float32).ravel(), tbl)
-    return out[:, 0, :f]
+    )
+    out = jax.lax.map(lambda iw: call(iw[0], iw[1], tbl), (ids, ws))
+    return out.reshape(n_chunks * chunk, f_pad)[:b, :f]

@@ -31,14 +31,12 @@ def _kernel(nbr_bin_ref, nbr_w_ref, out_ref, *, k: int, d: int):
     ws = nbr_w_ref[...]                    # [R, D] f32, 0 on padding
     r = bins.shape[0]
     iota = jax.lax.broadcasted_iota(jnp.int32, (r, k), 1)
-
-    def body(i, acc):
-        b = jax.lax.dynamic_slice(bins, (0, i), (r, 1))    # [R, 1]
-        w = jax.lax.dynamic_slice(ws, (0, i), (r, 1))
-        return acc + w * (b == iota).astype(jnp.float32)
-
-    out_ref[...] = jax.lax.fori_loop(
-        0, d, body, jnp.zeros((r, k), jnp.float32))
+    acc = jnp.zeros((r, k), jnp.float32)
+    # D is static and small (ELL width): unrolled static lane slices, since
+    # the TPU lowering has no dynamic lane slice
+    for i in range(d):
+        acc += ws[:, i:i + 1] * (bins[:, i:i + 1] == iota).astype(jnp.float32)
+    out_ref[...] = acc
 
 
 def plan(n: int, d: int, k: int, *, row_blk: int = 256) -> KernelPlan:

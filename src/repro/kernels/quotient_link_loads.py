@@ -17,6 +17,8 @@ subtree-XOR epilogue in the final grid step:
 Everything — scatter, GEMM, epilogue — is a single ``pallas_call``; no HBM
 round-trip for W. Block sizes: ``m_blk`` arcs per step (one-hot tiles
 ``m_blk x k`` live in VMEM), W scratch is ``k x k`` (1 MiB at k = 512).
+``m_blk`` is a multiple of 1024: the TPU compiler tiles 1-D 32-bit arrays
+in HBM as ``T(1024)`` and refuses a 1-D block that does not match.
 """
 from __future__ import annotations
 
@@ -60,7 +62,7 @@ def _kernel(bi_ref, bj_ref, w_ref, s_ref, fl_ref, out_ref, w_acc, *, k: int,
         out_ref[...] = fl_ref[...] * comm
 
 
-def plan(m: int, k: int, L: int, *, m_blk: int = 512) -> KernelPlan:
+def plan(m: int, k: int, L: int, *, m_blk: int = 1024) -> KernelPlan:
     """Static call plan: the single (arc-block) grid axis is sequential —
     every grid point writes the same [L] output block, carrying the k x k
     quotient accumulator in VMEM scratch; only the final step (epilogue)
@@ -98,7 +100,7 @@ def example_plan() -> KernelPlan:
 @functools.partial(jax.jit, static_argnames=("k", "m_blk", "interpret"))
 def quotient_link_loads(bin_i: jnp.ndarray, bin_j: jnp.ndarray,
                         weight: jnp.ndarray, subtree: jnp.ndarray,
-                        F_l: jnp.ndarray, *, k: int, m_blk: int = 512,
+                        F_l: jnp.ndarray, *, k: int, m_blk: int = 1024,
                         interpret: bool = False) -> jnp.ndarray:
     """Per-link communication cost ``F_l * comm(l)``. [L]
 

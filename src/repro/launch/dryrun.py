@@ -459,4 +459,6 @@ def _lint_gate(session: placement.PlacementSession) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

@@ -29,14 +29,6 @@ from __future__ import annotations
 import re
 from typing import Dict, List, Optional, Tuple
 
-def normalize_cost_analysis(c) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` returns a dict on current jax and a
-    one-dict-per-program list on older versions; normalize to one dict."""
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else None
-    return c or {}
-
-
 _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s4": 1, "u4": 1,
                 "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
                 "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,

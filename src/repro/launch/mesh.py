@@ -111,12 +111,6 @@ def make_production_mesh(*, multi_pod: bool = False,
     return make_machine_mesh(production_machine(multi_pod), device_order)
 
 
-def make_smoke_mesh():
-    """Whatever devices exist, as a 1D 'data' mesh (CPU tests)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
-
-
 # Historical hardware constants (TPU v5e-class machine, DESIGN.md
 # §Machine-models) — re-derived from the preset so legacy imports keep
 # working; new code reads per-leaf capacities off a MachineSpec instead.

@@ -506,7 +506,7 @@ class PlacementSession:
             }
         except Exception:                                # pragma: no cover
             mem_info = {}
-        agg = hlo_cost.normalize_cost_analysis(compiled.cost_analysis())
+        agg = compiled.cost_analysis() or {}
         agg_flops = float(agg.get("flops", 0.0))
         agg_bytes = float(agg.get("bytes accessed", 0.0))
         del compiled

@@ -135,7 +135,19 @@ def _lint_gate(arch_name: str, profile: str, session) -> None:
                          "finding(s)")
 
 
-def main() -> None:
+def _onto(mesh, tree):
+    """Re-place every array of ``tree`` on ``mesh`` under its current
+    PartitionSpec (after the topology-aware search reorders the devices)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    return jax.tree.map(
+        lambda x: jax.device_put(x, NamedSharding(
+            mesh, getattr(x.sharding, "spec", PartitionSpec()))), tree)
+
+
+def main(argv=None):
+    """Parse ``argv`` (default: the command line), train, and return the
+    loop's result (``LoopResult``, or ``SupervisedResult`` under
+    ``--fault-plan``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -193,7 +205,7 @@ def main() -> None:
     ap.add_argument("--prefetch", type=int, default=0, metavar="DEPTH",
                     help="async batch prefetch depth (0 = off; 2 = "
                          "double buffering)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     grad_compress = args.grad_compress_block or args.grad_compress
 
     from repro.core import machine as machine_lib
@@ -222,7 +234,16 @@ def main() -> None:
     else:
         from repro.models import gnn as mdl
 
-    params, _pspec = mdl.init(jax.random.PRNGKey(0), cfg, rules)
+    # parameters are born sharded by their spec tree: at full width the
+    # state does not fit one chip, so nothing is built whole on device 0
+    from repro.dist.sharding import sanitize_tree, tree_shardings
+    from repro.launch.steps import eval_shape_with_specs
+    key = jax.random.PRNGKey(0)
+    params_sds, pspec = eval_shape_with_specs(
+        lambda k: mdl.init(k, cfg, rules), key)
+    pspec = sanitize_tree(params_sds, pspec, mesh)
+    params = jax.jit(lambda k: mdl.init(k, cfg, rules)[0],
+                     out_shardings=tree_shardings(mesh, pspec))(key)
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
     print(f"arch={arch.name} params={n_params/1e6:.1f}M devices={n_dev}")
 
@@ -269,7 +290,8 @@ def main() -> None:
               f"{cache.traffic_bytes():.0f} B "
               f"(hit rate {cache.hit_rate:.2f})")
     else:
-        opt = adamw.init(params, ocfg)
+        opt = jax.jit(lambda p: adamw.init(p, ocfg), out_shardings=(
+            tree_shardings(mesh, adamw.state_specs(pspec))))(params)
         step = jax.jit(make_train_step(
             lambda p, b: mdl.loss_fn(p, b, cfg, rules), ocfg,
             grad_compress=grad_compress))
@@ -297,6 +319,7 @@ def main() -> None:
               f"{rep.identity['makespan']:.3e} -> searched "
               f"{rep.searched['makespan']:.3e} "
               f"({rep.n_candidates} candidates)")
+        params, opt = _onto(mesh, (params, opt))
 
     lcfg = loop.LoopConfig(total_steps=args.steps,
                            ckpt_every=args.ckpt_every,
@@ -322,7 +345,7 @@ def main() -> None:
         print(f"steps={sup.steps_run} attempts={sup.attempts} "
               f"recoveries={len(sup.recoveries)} "
               f"loss {sup.losses[0]:.4f} -> {sup.losses[-1]:.4f}")
-        return
+        return sup
     params, opt, result = loop.run(step, params, opt, batches, lcfg,
                                    mesh=mesh)
     print(f"steps={result.steps_run} resumed_from={result.resumed_from} "
@@ -333,7 +356,10 @@ def main() -> None:
         print(f"prefetch: depth={s['depth']} produced={s['produced']} "
               f"ready_hits={s['ready_hits']} "
               f"max_occupancy={s['max_occupancy']}")
+    return result
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

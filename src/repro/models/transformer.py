@@ -267,7 +267,6 @@ def _moe_routed_shardmap(p: Params, x: jnp.ndarray, cfg: TransformerConfig,
     top-k partial outputs. Expert FFN weights stay ZeRO-sharded over the
     fsdp axis and are all-gathered per layer (explicit FSDP).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -327,11 +326,11 @@ def _moe_routed_shardmap(p: Params, x: jnp.ndarray, cfg: TransformerConfig,
     batch_spec = P(dp_axes if len(dp_axes) != 1 else dp_axes[0], None)
     w_in_spec = P(ep, None, fsdp_axes[0] if fsdp_axes else None)
     wd_spec = P(ep, fsdp_axes[0] if fsdp_axes else None, None)
-    y, aux, drop = shard_map(
+    y, aux, drop = jax.shard_map(
         body, mesh=mesh,
         in_specs=(batch_spec, P(None, None), w_in_spec, w_in_spec, wd_spec),
         out_specs=(batch_spec, P(dp_axes), P(dp_axes)),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return y, MoEStats(aux_loss=aux.mean(), dropped_frac=drop.mean())
 

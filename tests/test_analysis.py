@@ -226,7 +226,7 @@ def test_sanitize_spec_strict_matches_static_lint():
     """The runtime twin: the same spec the static lint flags must raise
     under sanitize_spec(strict=True)."""
     from repro.dist.sharding import sanitize_spec
-    amesh = jax.sharding.AbstractMesh((("data", 2), ("model", 2)))
+    amesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
     static = shard_lint.lint_spec_tree(
         (jax.ShapeDtypeStruct((8, 8), jnp.float32),),
         (P("pod", "model"),), ("data", "model"), subject="twin")

@@ -68,12 +68,14 @@ def test_shard_is_noop_without_mesh():
 # ---------------------------------------------------------------------------
 
 def _mesh1():
-    return jax.make_mesh((1,), ("data",))
+    # the rules constrain under an Auto-typed mesh, as launch/mesh.py builds
+    return jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def _amesh(**sizes):
     """AbstractMesh carries axis sizes without needing physical devices."""
-    return jax.sharding.AbstractMesh(tuple(sizes.items()))
+    return jax.sharding.AbstractMesh(tuple(sizes.values()), tuple(sizes))
 
 
 def test_sanitize_drops_non_dividing_axis():

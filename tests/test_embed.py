@@ -144,6 +144,21 @@ def test_gather_combine_interpret_matches_ref():
             **tol)
 
 
+def test_gather_combine_chunked_bag_axis_matches_ref(monkeypatch):
+    """Batches over the scalar-prefetch bound run as several bag chunks
+    (the last one padded); the result is the unchunked reference."""
+    from repro.kernels import gather_combine as gc
+    monkeypatch.setattr(gc, "PREFETCH_SLOTS", 8)
+    rng = np.random.default_rng(5)
+    table = jnp.asarray(rng.normal(0, 1, (64, 40)).astype(np.float32))
+    idx = jnp.asarray(rng.integers(0, 64, (7, 3)).astype(np.int32))
+    w = jnp.asarray(rng.random((7, 3)).astype(np.float32))
+    got = kops.gather_combine(table, idx, w, interpret=True)
+    want = kref.gather_combine_ref(table, idx, w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6)
+
+
 def test_embedding_bag_backend_dispatch_parity():
     """The kernel path _bag_lookup now dispatches to must match the XLA
     fallback it used to pin (interpret vs ref)."""

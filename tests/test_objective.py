@@ -1,5 +1,6 @@
 """The paper's objective: JAX quotient-matrix implementation vs the
 path-walking oracle, across every topology generalization of §3.1."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -170,6 +171,44 @@ def test_permutation_batch_scorer_matches_single():
             jnp.asarray(T, jnp.float32), jnp.asarray(topo.subtree),
             jnp.asarray(c, jnp.int32)))
         np.testing.assert_allclose(want, one, rtol=1e-5, atol=1e-4)
+
+
+def _dot_precisions(fn, *args, **kw):
+    """The precision attribute of every GEMM in ``fn``'s lowered module."""
+    text = jax.jit(lambda *a: fn(*a, **kw)).lower(*args).as_text()
+    return [line.split("precision = ")[-1].split("]")[0] + "]"
+            if "precision = " in line else "DEFAULT"
+            for line in text.splitlines() if "stablehlo.dot_general" in line]
+
+
+@pytest.mark.parametrize("scorer", ["tree", "routing", "permutation",
+                                    "permutation_batch"])
+def test_load_gemms_run_at_highest_precision(scorer):
+    """A TPU rounds default-precision f32 GEMM operands to bf16, which
+    puts integer link loads above 256 off the path-walking oracle: every
+    load GEMM must ask for HIGHEST (visible in the jaxpr on any backend)."""
+    topo = balanced_tree((2, 2, 2))
+    k, f32, i32 = topo.k, jnp.float32, jnp.int32
+    W = jnp.ones((k, k), f32)
+    if scorer == "tree":
+        got = _dot_precisions(objective.link_loads_tree, W,
+                              jnp.asarray(topo.subtree))
+    elif scorer == "routing":
+        got = _dot_precisions(objective.link_loads_routing, W,
+                              jnp.ones((k, k, 3), f32))
+    elif scorer == "permutation":
+        got = _dot_precisions(objective.permutation_link_loads, W,
+                              jnp.asarray(topo.subtree), jnp.arange(k))
+    else:
+        pair = jnp.arange(k - 1, dtype=i32)
+        got = _dot_precisions(
+            objective.permutation_link_loads_batch,
+            jnp.arange(k, dtype=i32)[None], pair, pair + 1,
+            jnp.ones((k - 1,), f32), jnp.asarray(topo.lca_table()),
+            jnp.asarray(topo.subtree),
+            jnp.asarray(topo.node_subtree_indicator()),
+            k=k, n_nodes=topo.n_nodes)
+    assert got and all(p == "[HIGHEST, HIGHEST]" for p in got), got
 
 
 def test_makespan_tree_batch_matches_per_candidate():
