@@ -1,0 +1,7 @@
+"""Percent of the traced serving window in which no operation ran on
+the device."""
+
+
+def read(ctx):
+    share = ctx["trace"].idle_share
+    return None if share is None else 100.0 * share
