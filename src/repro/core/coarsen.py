@@ -21,6 +21,7 @@ from typing import List, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.graph.graph import Graph, from_edges
 
 
@@ -211,28 +212,29 @@ def coarsen_device(g: Graph, k: int, seed: int = 0, max_levels: int = 40,
     for lvl in range(max_levels):
         if cur.n_nodes <= coarse_factor * k or cur.n_arcs == 0:
             break
-        n_pad, m_pad = _pow2(cur.n_nodes), _pow2(cur.n_arcs)
-        s = jnp.asarray(np.pad(cur.senders.astype(np.int32),
-                               (0, m_pad - cur.n_arcs)))
-        r = jnp.asarray(np.pad(cur.receivers.astype(np.int32),
-                               (0, m_pad - cur.n_arcs)))
-        w = jnp.asarray(np.pad(cur.edge_weight.astype(np.float32),
-                               (0, m_pad - cur.n_arcs)))
-        nw = jnp.asarray(np.pad(cur.node_weight.astype(np.float32),
-                                (0, n_pad - cur.n_nodes)))
-        cid, nc, nw_c, cu_e, cv_e, w_e, m_new = step(
-            s, r, w, nw, jnp.int32(cur.n_nodes), jnp.int32(cur.n_arcs),
-            jax.random.fold_in(key, lvl), n_pad=n_pad)
-        nc, m_new = int(nc), int(m_new)
-        if nc >= cur.n_nodes * (1.0 - min_reduction):
-            break
-        nxt = from_edges(
-            nc, np.asarray(cu_e[:m_new], dtype=np.int64),
-            np.asarray(cv_e[:m_new], dtype=np.int64),
-            np.asarray(w_e[:m_new], dtype=np.float32),
-            np.asarray(nw_c[:nc], dtype=np.float32), dedup=False)
-        mapping = np.asarray(cid[:cur.n_nodes], dtype=np.int64)
-        levels[-1] = Level(graph=levels[-1].graph, fine_to_coarse=mapping)
-        levels.append(Level(graph=nxt, fine_to_coarse=None))  # type: ignore[arg-type]
-        cur = nxt
+        with obs.span("coarsen.level"):
+            n_pad, m_pad = _pow2(cur.n_nodes), _pow2(cur.n_arcs)
+            s = jnp.asarray(np.pad(cur.senders.astype(np.int32),
+                                   (0, m_pad - cur.n_arcs)))
+            r = jnp.asarray(np.pad(cur.receivers.astype(np.int32),
+                                   (0, m_pad - cur.n_arcs)))
+            w = jnp.asarray(np.pad(cur.edge_weight.astype(np.float32),
+                                   (0, m_pad - cur.n_arcs)))
+            nw = jnp.asarray(np.pad(cur.node_weight.astype(np.float32),
+                                    (0, n_pad - cur.n_nodes)))
+            cid, nc, nw_c, cu_e, cv_e, w_e, m_new = step(
+                s, r, w, nw, jnp.int32(cur.n_nodes), jnp.int32(cur.n_arcs),
+                jax.random.fold_in(key, lvl), n_pad=n_pad)
+            nc, m_new = int(nc), int(m_new)
+            if nc >= cur.n_nodes * (1.0 - min_reduction):
+                break
+            nxt = from_edges(
+                nc, np.asarray(cu_e[:m_new], dtype=np.int64),
+                np.asarray(cv_e[:m_new], dtype=np.int64),
+                np.asarray(w_e[:m_new], dtype=np.float32),
+                np.asarray(nw_c[:nc], dtype=np.float32), dedup=False)
+            mapping = np.asarray(cid[:cur.n_nodes], dtype=np.int64)
+            levels[-1] = Level(graph=levels[-1].graph, fine_to_coarse=mapping)
+            levels.append(Level(graph=nxt, fine_to_coarse=None))  # type: ignore[arg-type]
+            cur = nxt
     return levels

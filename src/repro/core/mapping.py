@@ -26,6 +26,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import objective
 from repro.core.topology import RoutingTopology, Topology, TreeTopology
 from repro.graph.graph import Graph
@@ -617,11 +618,13 @@ def search(mesh_shape: Sequence[int], topo: Optional[Topology],
     ``machine=`` (a ``core.machine.MachineSpec``) derives the topology
     from the declarative machine model.
     """
-    return search_mesh_mapping(mesh_shape, {}, topo, traffic=traffic,
-                               warm_starts=warm_starts, n_random=n_random,
-                               seed=seed, recursive=recursive, chunk=chunk,
-                               max_axis_perms=max_axis_perms,
-                               machine=machine)
+    with obs.span("map.search"):
+        return search_mesh_mapping(mesh_shape, {}, topo, traffic=traffic,
+                                   warm_starts=warm_starts,
+                                   n_random=n_random, seed=seed,
+                                   recursive=recursive, chunk=chunk,
+                                   max_axis_perms=max_axis_perms,
+                                   machine=machine)
 
 
 def expert_placement(traffic: np.ndarray, expert_flops: np.ndarray,

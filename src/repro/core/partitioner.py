@@ -24,6 +24,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import objective, refine as refine_mod
 from repro.core.coarsen import coarsen, coarsen_device
 from repro.core.initial import (initial_partition, initial_partition_device,
@@ -113,31 +114,37 @@ def partition(g: Graph, topo: TreeTopology,
         raise ValueError(f"backend must be 'host' or 'device', "
                          f"got {cfg.backend!r}")
     t0 = time.time()
-    coarsen_fn = coarsen_device if cfg.backend == "device" else coarsen
-    levels = coarsen_fn(g, topo.k, seed=cfg.seed,
-                        coarse_factor=cfg.coarse_factor,
-                        max_levels=cfg.max_levels)
-    coarsest = levels[-1].graph
-    history: List[float] = []
-    # uncoarsen: every level refines all S partitions in ONE vmapped scan
-    # (refine_batch; seeds=1 is the classic single-trajectory V-cycle —
-    # slot 0 is pinned to refine() by test). The refine rounds are
-    # GEMM-bound, so S restarts cost far less than S sequential runs; the
-    # winner is the seed with the smallest true makespan on the finest
-    # graph.
-    parts = _initial_parts(coarsest, topo, cfg)
-    ms = None
-    for li in range(len(levels) - 1, -1, -1):
-        lg = levels[li].graph
-        rcfg = cfg.refine
-        if li == 0 and cfg.final_rounds is not None:
-            rcfg = dataclasses.replace(rcfg, rounds=cfg.final_rounds)
-        parts, ms, _ = refine_mod.refine_batch(lg, topo, parts, rcfg)
-        history.append(float(ms.min()))
-        if li > 0:
-            parts = parts[:, levels[li - 1].fine_to_coarse]
-    part = parts[int(np.argmin(ms))]
-    res = _evaluate(g, topo, part)
+    with obs.span("partition"):
+        coarsen_fn = coarsen_device if cfg.backend == "device" else coarsen
+        with obs.span("partition.coarsen"):
+            levels = coarsen_fn(g, topo.k, seed=cfg.seed,
+                                coarse_factor=cfg.coarse_factor,
+                                max_levels=cfg.max_levels)
+        coarsest = levels[-1].graph
+        history: List[float] = []
+        # uncoarsen: every level refines all S partitions in ONE vmapped
+        # scan (refine_batch; seeds=1 is the classic single-trajectory
+        # V-cycle — slot 0 is pinned to refine() by test). The refine
+        # rounds are GEMM-bound, so S restarts cost far less than S
+        # sequential runs; the winner is the seed with the smallest true
+        # makespan on the finest graph.
+        with obs.span("partition.initial"):
+            parts = _initial_parts(coarsest, topo, cfg)
+        ms = None
+        for li in range(len(levels) - 1, -1, -1):
+            lg = levels[li].graph
+            rcfg = cfg.refine
+            if li == 0 and cfg.final_rounds is not None:
+                rcfg = dataclasses.replace(rcfg, rounds=cfg.final_rounds)
+            with obs.span("partition.refine"):
+                parts, ms, _ = refine_mod.refine_batch(lg, topo, parts, rcfg)
+            history.append(float(ms.min()))
+            if li > 0:
+                with obs.span("partition.project"):
+                    parts = parts[:, levels[li - 1].fine_to_coarse]
+        part = parts[int(np.argmin(ms))]
+        with obs.span("partition.evaluate"):
+            res = _evaluate(g, topo, part)
     res.seconds = time.time() - t0
     res.level_makespans = history
     return res

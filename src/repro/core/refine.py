@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import objective
 from repro.core.topology import TreeTopology
 from repro.graph.graph import Graph
@@ -322,5 +323,22 @@ def refine_batch(g: Graph, topo: TreeTopology, parts: np.ndarray,
         k=k, rounds=cfg.rounds, dense=bool(dense), damping=cfg.damping,
         temp0=cfg.temp0, temp_min=cfg.temp_min, anneal=cfg.anneal,
         inflow_slack=cfg.inflow_slack)
-    return (np.asarray(best_parts), np.asarray(best_ms),
-            jax.tree.map(np.asarray, stats))
+    with obs.span("refine.pull"):
+        best_parts, best_ms = np.asarray(best_parts), np.asarray(best_ms)
+        stats = jax.tree.map(np.asarray, stats)
+    if obs.on():
+        obs.add("refine.rounds", stats.makespan.size)
+        obs.add("refine.rounds_to_best",
+                int(rounds_to_best(stats.makespan, best_ms).sum()))
+    return best_parts, best_ms, stats
+
+
+def rounds_to_best(makespan: np.ndarray, best_ms: np.ndarray) -> np.ndarray:
+    """[S] rounds each slot of :func:`refine_batch` ran until it reached
+    the partition it returns, from its per-round makespans ``[S, rounds]``
+    and best makespans ``[S]``: the first round at the best, or 0 where no
+    round reached it (the start stood). Later rounds cannot change the
+    result. A round that only ties the start counts as reaching it, so the
+    count errs high, never low."""
+    return np.where(makespan.min(axis=1) <= best_ms,
+                    makespan.argmin(axis=1) + 1, 0)

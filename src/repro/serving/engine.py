@@ -39,6 +39,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import machine as machine_lib
 from repro.serving.kv_cache import PagedKVCache
 from repro.serving.scheduler import Request, Scheduler
@@ -116,15 +117,19 @@ class ServeReport:
 def _jitted_decode(cfg, rules):
     """One compiled paged step per (cfg, rules) — engines share it, so a
     bench spinning up several engines (continuous vs static vs placed)
-    compiles once instead of per engine."""
-    import functools
-
+    compiles once instead of per engine. The program is named
+    ``jit_paged_decode_step`` in a profile."""
     import jax
 
-    from repro.serving.paged_decode import paged_decode_step
-    return jax.jit(
-        functools.partial(paged_decode_step, cfg=cfg, rules=rules),
-        donate_argnums=(1, 2))
+    from repro.serving import paged_decode
+
+    def paged_decode_step(params, k_pool, v_pool, page_table, lengths,
+                          tokens):
+        return paged_decode.paged_decode_step(
+            params, k_pool, v_pool, page_table, lengths, tokens, cfg=cfg,
+            rules=rules)
+
+    return jax.jit(paged_decode_step, donate_argnums=(1, 2))
 
 
 class ServingEngine:
@@ -161,7 +166,8 @@ class ServingEngine:
         self._tokens_reprefilled = 0
         self._rid = 0
         self._step = 0
-        self._occupancy: List[int] = []
+        self._occ_steps = 0            # steps, idle backoff steps too
+        self._occ_slots = 0            # active slots summed over them
         self._base_key = jax.random.PRNGKey(ecfg.seed)
         if injector is not None and self.page_to_device is None:
             # a death can fire before the first placement epoch; start
@@ -206,48 +212,73 @@ class ServingEngine:
     def step(self) -> None:
         """One engine step: fire due faults, admit, batched decode,
         sample, advance."""
+        with obs.span("serve.step"):
+            self._one_step()
+
+    def _one_step(self) -> None:
         import jax.numpy as jnp
         ecfg = self.ecfg
         if self.injector is not None:
             for ev in self.injector.fire(self._step):
                 self._handle_fault(ev)
-        self.scheduler.admit(self._step,
-                             only_when_idle=ecfg.static_batching)
-        inputs = self.scheduler.step_inputs()
+        with obs.span("serve.admit"):
+            admitted = self.scheduler.admit(
+                self._step, only_when_idle=ecfg.static_batching)
+            if obs.on() and admitted:
+                now = time.perf_counter()
+                obs.add("serve.admitted", len(admitted))
+                obs.add("serve.queue_wait_s",
+                        sum(now - r.queued_t for r in admitted))
+        with obs.span("serve.inputs"):
+            inputs = self.scheduler.step_inputs()
+            n = self.cache.n_slots
+            tokens = np.zeros((n, 1), dtype=np.int32)
+            lengths = np.zeros((n,), dtype=np.int32)
+            rids = np.full((n,), -1, dtype=np.int32)
+            for si in inputs:
+                tokens[si.slot, 0] = si.token
+                lengths[si.slot] = si.pos
+                rids[si.slot] = si.rid
         if not inputs:
             if self.scheduler.queue:
                 head = self.scheduler.queue[0]
                 if head.not_before > self._step:
                     # every queued request is waiting out its retry
                     # backoff: an idle step passes, time advances
-                    self._occupancy.append(0)
+                    self._occ_steps += 1
                     self._step += 1
                     return
                 raise RuntimeError(
                     "no active slot and the queue head cannot be "
                     "admitted — infeasible request escaped submit()")
             return
-        n = self.cache.n_slots
-        tokens = np.zeros((n, 1), dtype=np.int32)
-        lengths = np.zeros((n,), dtype=np.int32)
-        rids = np.full((n,), -1, dtype=np.int32)
-        for si in inputs:
-            tokens[si.slot, 0] = si.token
-            lengths[si.slot] = si.pos
-            rids[si.slot] = si.rid
-        logits, self.cache.k_pool, self.cache.v_pool = self._decode(
-            self.params, self.cache.k_pool, self.cache.v_pool,
-            jnp.asarray(self.cache.page_table), jnp.asarray(lengths),
-            jnp.asarray(tokens))
-        sampled = np.asarray(self._sample(logits, jnp.asarray(rids),
-                                          jnp.asarray(lengths)))
-        # the step read pages [0, pos] of every active slot
-        self.cache.record_access({si.slot: si.pos + 1 for si in inputs})
-        self._occupancy.append(len(inputs))
-        for si in inputs:
-            self.scheduler.advance(
-                si.slot, self._step,
-                int(sampled[si.slot]) if si.needs_sample else None)
+        with obs.span("serve.dispatch"):
+            logits, self.cache.k_pool, self.cache.v_pool = self._decode(
+                self.params, self.cache.k_pool, self.cache.v_pool,
+                jnp.asarray(self.cache.page_table), jnp.asarray(lengths),
+                jnp.asarray(tokens))
+            sampled = self._sample(logits, jnp.asarray(rids),
+                                   jnp.asarray(lengths))
+        with obs.span("serve.pull"):
+            sampled = np.asarray(sampled)
+        if obs.on():
+            # the step gathers every page-table entry of every slot, and
+            # attends to the pages holding positions [0, pos] of each
+            # active one
+            obs.add("decode.pages_gathered", self.cache.page_table.size)
+            obs.add("decode.pages_live", sum(
+                self.cache.pages_needed(si.pos + 1) for si in inputs))
+        with obs.span("serve.advance"):
+            # the step read pages [0, pos] of every active slot
+            with obs.span("serve.record_access"):
+                self.cache.record_access(
+                    {si.slot: si.pos + 1 for si in inputs})
+            self._occ_steps += 1
+            self._occ_slots += len(inputs)
+            for si in inputs:
+                self.scheduler.advance(
+                    si.slot, self._step,
+                    int(sampled[si.slot]) if si.needs_sample else None)
         self._step += 1
         if (ecfg.replace_every > 0
                 and self._step % ecfg.replace_every == 0):
@@ -374,8 +405,8 @@ class ServingEngine:
         def pct(a, q):
             return float(np.percentile(a, q)) if a.size else 0.0
 
-        occ = (float(np.mean(self._occupancy)) / self.cache.n_slots
-               if self._occupancy else 0.0)
+        occ = (self._occ_slots / self._occ_steps / self.cache.n_slots
+               if self._occ_steps else 0.0)
         failed = self.scheduler.failed
         return ServeReport(
             n_requests=len(done), steps=self._step,

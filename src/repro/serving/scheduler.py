@@ -29,6 +29,7 @@ resumed continuation is bit-identical to the uninterrupted one.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence
 
@@ -45,6 +46,7 @@ class Request:
     prompt: np.ndarray                 # [prompt_len] int32
     max_new_tokens: int
     submit_step: int = -1
+    queued_t: float = 0.0              # perf_counter when last queued
     admit_step: int = -1
     first_token_step: int = -1
     done_step: int = -1
@@ -117,6 +119,7 @@ class Scheduler:
             raise ValueError(f"request {req.rid}: prompt and gen lengths "
                              "must both be >= 1")
         req.submit_step = step
+        req.queued_t = time.perf_counter()
         self.queue.append(req)
 
     # -- per-step control ------------------------------------------------
@@ -231,6 +234,7 @@ class Scheduler:
         req.retries += 1
         req.requeue_steps.append(step)
         req.not_before = not_before
+        req.queued_t = time.perf_counter()
         self.queue.append(req)
         return req
 

@@ -318,6 +318,9 @@ def test_engine_completes_all_and_reports():
         assert len(r["generated"]) == r["max_new_tokens"]
     assert rep.placements, "re-placement policy never ran"
     assert rep.latency_steps_p99 >= rep.latency_steps_p50 > 0
+    # a request holds its slot for prompt + gen - 1 steps; 2 slots
+    slot_steps = sum(len(p) + g - 1 for p, g in work)
+    assert rep.mean_batch_occupancy == round(slot_steps / rep.steps / 2, 4)
     import json
     json.loads(rep.to_json())                        # trace round-trips
 
