@@ -3,23 +3,28 @@
 This is the hardware adaptation of the paper's implied refinement loop
 (DESIGN.md §2): classical partitioners refine with priority-queue FM — a
 sequential, pointer-chasing pattern with no TPU analogue. Here every round is
-a handful of GEMMs/segment ops over the whole vertex set:
+a few gathers and scatters over the arc list plus small GEMMs over the bins:
 
-  1. Score the current assignment: per-bin loads ``comp`` and per-link loads
-     ``comm`` via the quotient-matrix algebra (objective.py).
-  2. Price bins and links with the gradient of the annealed soft-max
-     potential (softmax weights concentrate on the bottleneck terms).
-  3. Build the ``k x k`` *price-distance* matrix
+  1. Price bins and links with the gradient of the annealed soft-max
+     potential (softmax weights concentrate on the bottleneck terms), from
+     the per-bin loads ``comp`` and per-link loads ``comm`` of the current
+     assignment, carried in the state from the round that accepted it.
+  2. Build the ``k x k`` *price-distance* matrix
      ``pi[a, b] = sum_l price_l * [l on path(a,b)]`` — two GEMMs against the
      subtree indicator.
-  4. Every vertex evaluates candidate destination bins against ``pi`` and
+  3. Every vertex evaluates candidate destination bins against ``pi`` and
      the bin prices, either densely (all k bins, via the ``partition_gain``
      connectivity kernel) or sparsely (one sampled candidate per vertex,
      O(m) via arc gathers) — the dense mode is used on coarse levels, the
      sparse mode on multi-million-vertex fine levels.
-  5. A damped, inflow-capped subset of positive-gain moves is applied;
+  4. A damped, inflow-capped subset of positive-gain moves is applied;
      acceptance of the *round* is judged by the true (hard-max) makespan, so
      the smoothing never corrupts the objective — it only prices moves.
+  5. The moved assignment is scored once (one quotient-matrix scatter into
+     ``k^2`` cells): that scoring both judges the round and prices the next.
+
+What depends on the graph alone — each vertex's heaviest arc — is found
+once per call, outside the rounds.
 
 The whole loop is one ``lax.scan`` under ``jit``; the temperature anneals
 from ``temp0`` toward ``temp_min`` so early rounds spread pressure across
@@ -56,6 +61,8 @@ class RefineConfig:
 
 class RefineState(NamedTuple):
     part: jnp.ndarray        # [n] int32 current assignment
+    comp: jnp.ndarray        # [k] RAW per-bin loads of ``part``
+    comm: jnp.ndarray        # [L] per-link loads of ``part``
     best_part: jnp.ndarray   # [n] int32 best-so-far under true makespan
     best_m: jnp.ndarray      # scalar best true makespan
     temp: jnp.ndarray        # scalar
@@ -78,6 +85,19 @@ def price_matrix(g_link: jnp.ndarray, subtree: jnp.ndarray) -> jnp.ndarray:
     u = g_link @ S                       # [k] sum_l g_l S_la
     cross = S.T @ (g_link[:, None] * S)  # [k, k]
     return u[:, None] + u[None, :] - 2.0 * cross
+
+
+def _score(part, senders, receivers, edge_weight, node_weight, subtree, F_l,
+           k, speed=None):
+    """(RAW per-bin loads [k], breakdown) of ``part``. The operations of
+    ``objective.makespan_tree``; the raw load is kept because pricing and
+    the inflow cap take it, and the breakdown's ``comp`` is ``raw / speed``
+    exactly as ``comp_loads`` divides."""
+    comp = objective.comp_loads(part, node_weight, k)
+    W = objective.quotient_matrix(part, senders, receivers, edge_weight, k)
+    comm = objective.link_loads_tree(W, subtree)
+    comp_n = comp if speed is None else comp / speed
+    return comp, objective.makespan_from_parts(comp_n, comm, F_l)
 
 
 def _prices(comp, comm, F_l, temp, speed=None):
@@ -114,12 +134,9 @@ def _apply_moves(part, cand, gain, node_weight, comp, key, k, damping,
 # Dense mode: every vertex scores all k destination bins.
 # ---------------------------------------------------------------------------
 
-def _dense_round(part, senders, receivers, edge_weight, node_weight,
-                 subtree, F_l, k, temp, key, damping, inflow_slack,
-                 speed=None):
-    comp = objective.comp_loads(part, node_weight, k)
-    W = objective.quotient_matrix(part, senders, receivers, edge_weight, k)
-    comm = objective.link_loads_tree(W, subtree)
+def _dense_round(part, comp, comm, senders, receivers, edge_weight,
+                 node_weight, subtree, F_l, k, temp, key, damping,
+                 inflow_slack, speed=None):
     # g_comp prices RAW load (1/speed folded in by load_gradients), so the
     # gain formula below is unchanged on heterogeneous machines
     g_comp, g_link = _prices(comp, comm, F_l, temp, speed)
@@ -142,29 +159,36 @@ def _dense_round(part, senders, receivers, edge_weight, node_weight,
 # Sparse mode: one sampled candidate bin per vertex per round. O(m).
 # ---------------------------------------------------------------------------
 
-def _sample_candidates(part, senders, receivers, edge_weight, offsets_pad,
-                       degrees, g_comp, mode, key, k, n):
-    """Candidate destination bin per vertex.
+def _heavy_arcs(senders, edge_weight, n):
+    """[n] index of each vertex's heaviest outgoing arc (the largest arc
+    index among ties; 0 for a vertex with no arcs). A function of the graph
+    alone, so refinement finds it once and not every round.
 
-    mode 0: bin of the heaviest incident arc (strongest pull)
-    mode 1: bin of a uniformly random incident arc (exploration)
-    mode 2: cheapest-priced bin (load escape hatch for bottleneck bins)
-    """
-    nbr_bin = part[receivers].astype(jnp.int32)
-
-    # heaviest arc per sender: exact two-pass segment argmax. (A float32
-    # composite key ``w * (m+1) + arc`` loses the packed arc index once the
-    # arc count nears 2^24 — multi-million-edge graphs would sample a wrong,
-    # possibly out-of-segment arc. Two segment_max passes are precision-safe
-    # at any size: first the per-segment max weight, then the largest arc
-    # index among the arcs attaining it.)
+    Exact two-pass segment argmax. (A float32 composite key
+    ``w * (m+1) + arc`` loses the packed arc index once the arc count nears
+    2^24 — multi-million-edge graphs would sample a wrong, possibly
+    out-of-segment arc. Two segment_max passes are precision-safe at any
+    size: first the per-segment max weight, then the largest arc index
+    among the arcs attaining it.)"""
     m = senders.shape[0]
     w32 = edge_weight.astype(jnp.float32)
     seg_max = jax.ops.segment_max(w32, senders, num_segments=n)
     at_max = w32 >= seg_max[senders]          # exact: compares its own max
     arc_ids = jnp.where(at_max, jnp.arange(m, dtype=jnp.int32), -1)
-    best_arc = jnp.clip(jax.ops.segment_max(arc_ids, senders, num_segments=n),
-                        0, m - 1)
+    return jnp.clip(jax.ops.segment_max(arc_ids, senders, num_segments=n),
+                    0, m - 1)
+
+
+def _sample_candidates(part, receivers, best_arc, offsets_pad, degrees,
+                       g_comp, mode, key, n):
+    """Candidate destination bin per vertex.
+
+    mode 0: bin of the heaviest incident arc ``best_arc`` (strongest pull)
+    mode 1: bin of a uniformly random incident arc (exploration)
+    mode 2: cheapest-priced bin (load escape hatch for bottleneck bins)
+    """
+    m = receivers.shape[0]
+    nbr_bin = part[receivers].astype(jnp.int32)
     heavy = nbr_bin[best_arc]
 
     rand_off = (jax.random.uniform(key, (n,)) * jnp.maximum(degrees, 1)).astype(jnp.int32)
@@ -176,19 +200,16 @@ def _sample_candidates(part, senders, receivers, edge_weight, offsets_pad,
     return jnp.where(degrees > 0, cand, part.astype(jnp.int32)).astype(part.dtype)
 
 
-def _sparse_round(part, senders, receivers, edge_weight, node_weight,
-                  offsets_pad, degrees, subtree, F_l, k, temp, key, mode,
-                  damping, inflow_slack, speed=None):
+def _sparse_round(part, comp, comm, senders, receivers, edge_weight,
+                  node_weight, offsets_pad, degrees, best_arc, subtree, F_l, k,
+                  temp, key, mode, damping, inflow_slack, speed=None):
     n = part.shape[0]
-    comp = objective.comp_loads(part, node_weight, k)
-    W = objective.quotient_matrix(part, senders, receivers, edge_weight, k)
-    comm = objective.link_loads_tree(W, subtree)
     g_comp, g_link = _prices(comp, comm, F_l, temp, speed)
     pi = price_matrix(g_link, subtree)
 
     k_cand, k_move = jax.random.split(key)
-    cand = _sample_candidates(part, senders, receivers, edge_weight,
-                              offsets_pad, degrees, g_comp, mode, k_cand, k, n)
+    cand = _sample_candidates(part, receivers, best_arc, offsets_pad,
+                              degrees, g_comp, mode, k_cand, n)
 
     a_s = part[senders].astype(jnp.int32)
     b_r = part[receivers].astype(jnp.int32)
@@ -210,35 +231,41 @@ def _refine_core(part0, senders, receivers, edge_weight, node_weight,
                  offsets_pad, degrees, subtree, F_l, key, speed=None, *,
                  k, rounds, dense, damping, temp0, temp_min, anneal,
                  inflow_slack):
+    score = functools.partial(_score, senders=senders, receivers=receivers,
+                              edge_weight=edge_weight, node_weight=node_weight,
+                              subtree=subtree, F_l=F_l, k=k, speed=speed)
+    best_arc = (None if dense
+                else _heavy_arcs(senders, edge_weight, part0.shape[0]))
+
     def body(state: RefineState, ridx):
         key, sub = jax.random.split(state.key)
         if dense:
             part, moved = _dense_round(
-                state.part, senders, receivers, edge_weight, node_weight,
-                subtree, F_l, k, state.temp, sub, damping, inflow_slack,
-                speed)
+                state.part, state.comp, state.comm, senders, receivers,
+                edge_weight, node_weight, subtree, F_l, k, state.temp, sub,
+                damping, inflow_slack, speed)
         else:
             mode = ridx % 3
             part, moved = _sparse_round(
-                state.part, senders, receivers, edge_weight, node_weight,
-                offsets_pad, degrees, subtree, F_l, k, state.temp, sub, mode,
-                damping, inflow_slack, speed)
-        # one breakdown per round: acceptance and stats share it
-        br = objective.makespan_tree(part, senders, receivers, edge_weight,
-                                     node_weight, subtree, F_l, k=k,
-                                     speed=speed)
+                state.part, state.comp, state.comm, senders, receivers,
+                edge_weight, node_weight, offsets_pad, degrees, best_arc,
+                subtree, F_l, k, state.temp, sub, mode, damping, inflow_slack,
+                speed)
+        # one scoring per round: acceptance, stats and the next round's
+        # prices share it
+        comp, br = score(part)
         m = br.makespan
         better = m < state.best_m
         best_part = jnp.where(better, part, state.best_part)
         best_m = jnp.minimum(m, state.best_m)
         temp = jnp.maximum(state.temp * anneal, temp_min)
         stats = RefineStats(m, br.comp_max, br.comm_max, moved)
-        return RefineState(part, best_part, best_m, temp, key), stats
+        return RefineState(part, comp, br.comm, best_part, best_m, temp,
+                           key), stats
 
-    m0 = objective.makespan_tree(part0, senders, receivers, edge_weight,
-                                 node_weight, subtree, F_l, k=k,
-                                 speed=speed).makespan
-    init = RefineState(part0, part0, m0, jnp.float32(temp0), key)
+    comp0, br0 = score(part0)
+    init = RefineState(part0, comp0, br0.comm, part0, br0.makespan,
+                       jnp.float32(temp0), key)
     final, stats = jax.lax.scan(body, init, jnp.arange(rounds))
     return final.best_part, final.best_m, stats
 

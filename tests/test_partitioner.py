@@ -1,12 +1,15 @@
 """Multilevel makespan partitioner: optimality gap vs brute force (C5),
 improvement over random, oracle cross-check, baseline comparisons."""
+import functools
+
 import numpy as np
 import pytest
 
 from repro.core import baselines, reference
 from repro.core.partitioner import PartitionConfig, partition, verify
 from repro.core.refine import RefineConfig, refine
-from repro.core.topology import balanced_tree, flat_topology, production_tree
+from repro.core.topology import (balanced_tree, flat_topology,
+                                 production_tree, with_bin_speed)
 from repro.graph.generators import grid2d, rmat, weighted_nodes
 
 
@@ -95,8 +98,9 @@ def test_refine_batch_slot0_matches_refine():
 
 def test_sampled_heavy_arc_is_exact():
     """The sparse-mode candidate sampler must pick the bin of the true
-    heaviest incident arc (two-pass segment argmax; the old float32
-    composite key broke down on large arc counts)."""
+    heaviest incident arc (two-pass segment argmax in ``_heavy_arcs``, found
+    once per refinement; the old float32 composite key broke down on large
+    arc counts)."""
     import jax
     import jax.numpy as jnp
     from repro.core import refine as refine_mod
@@ -104,11 +108,13 @@ def test_sampled_heavy_arc_is_exact():
     g = rmat(50, 200, seed=7)
     k = 4
     part = rng.integers(0, k, g.n_nodes).astype(np.int32)
+    best_arc = refine_mod._heavy_arcs(jnp.asarray(g.senders),
+                                      jnp.asarray(g.edge_weight), g.n_nodes)
     cand = refine_mod._sample_candidates(
-        jnp.asarray(part), jnp.asarray(g.senders), jnp.asarray(g.receivers),
-        jnp.asarray(g.edge_weight), jnp.asarray(g.offsets[:-1], jnp.int32),
+        jnp.asarray(part), jnp.asarray(g.receivers), best_arc,
+        jnp.asarray(g.offsets[:-1], jnp.int32),
         jnp.asarray(g.degrees(), jnp.int32), jnp.zeros(k), 0,
-        jax.random.PRNGKey(0), k, g.n_nodes)
+        jax.random.PRNGKey(0), g.n_nodes)
     cand = np.asarray(cand)
     for v in range(g.n_nodes):
         lo, hi = g.offsets[v], g.offsets[v + 1]
@@ -120,6 +126,108 @@ def test_sampled_heavy_arc_is_exact():
         best_bins = {int(part[g.receivers[lo + i]])
                      for i in np.nonzero(w >= w.max())[0]}
         assert int(cand[v]) in best_bins
+
+
+def _trajectory_case(mode, hetero):
+    """A refinement problem in sparse (n*k = 256,000 > dense_threshold) or
+    dense mode, with link costs low enough that compute and links compete,
+    fractional vertex weights, and optionally bins at half speed."""
+    if mode == "sparse":
+        g = weighted_nodes(rmat(1000, 6000, seed=11), seed=11, lo=0.2, hi=5.0)
+        topo = balanced_tree((16, 16), level_cost=(0.04, 0.01))
+    else:
+        g = weighted_nodes(rmat(200, 800, seed=12), seed=12, lo=0.2, hi=5.0)
+        topo = balanced_tree((2, 4), level_cost=(0.4, 0.1))
+    if hetero:
+        topo = with_bin_speed(
+            topo, np.where(np.arange(topo.k) % 3 == 0, 0.5, 1.0))
+    part0 = baselines.random_partition(g.n_nodes, topo.k, seed=3)
+    cfg = RefineConfig(rounds=8, seed=5)
+    assert (g.n_nodes * topo.k > cfg.dense_threshold) == (mode == "sparse")
+    return g, topo, part0, cfg
+
+
+# Recorded on XLA:CPU from the refinement that scored every partition twice
+# a round and searched heavy arcs every round: (sha256[:16] of best_part's
+# int32 bytes, best_m, RefineStats). Passes removed since must leave every
+# bit of the trajectory as it was.
+_TRAJECTORIES = {
+    ("sparse", False): ("cb5f52f80de1d427", 39.23999786376953, dict(
+        makespan=[56.599998474121094, 50.84000015258789, 50.84000015258789, 49.31999969482422, 39.23999786376953, 39.23999786376953, 45.63999938964844, 45.36000061035156],
+        comp_max=[21.968767166137695, 23.943756103515625, 23.943756103515625, 35.17722702026367, 24.613998413085938, 28.325851440429688, 30.91587257385254, 30.301044464111328],
+        comm_max=[56.599998474121094, 50.84000015258789, 50.84000015258789, 49.31999969482422, 39.23999786376953, 39.23999786376953, 45.63999938964844, 45.36000061035156],
+        moved=[182, 152, 6, 101, 104, 8, 96, 99])),
+    ("sparse", True): ("71f515adeb126f07", 38.47999954223633, dict(
+        makespan=[56.84000015258789, 52.119998931884766, 52.119998931884766, 50.52000045776367, 38.47999954223633, 41.79999923706055, 53.23999786376953, 47.68000030517578],
+        comp_max=[38.84343719482422, 33.75094985961914, 33.75094985961914, 33.40046310424805, 30.877817153930664, 38.105445861816406, 37.34116744995117, 30.972604751586914],
+        comm_max=[56.84000015258789, 52.119998931884766, 52.119998931884766, 50.52000045776367, 38.47999954223633, 41.79999923706055, 53.23999786376953, 47.68000030517578],
+        moved=[186, 144, 10, 97, 98, 13, 85, 99])),
+    ("dense", False): ("5d28070530082842", 124.3143310546875, dict(
+        makespan=[152.0, 149.1999969482422, 137.60000610351562, 130.8000030517578, 127.55146026611328, 133.0576171875, 124.3143310546875, 144.36215209960938],
+        comp_max=[79.22832489013672, 84.15959167480469, 95.36636352539062, 103.55293273925781, 127.55146026611328, 133.0576171875, 124.3143310546875, 144.36215209960938],
+        comm_max=[152.0, 149.1999969482422, 137.60000610351562, 130.8000030517578, 96.80000305175781, 90.4000015258789, 84.0, 72.4000015258789],
+        moved=[31, 29, 32, 46, 70, 72, 46, 84])),
+    ("dense", True): ("4f8d90800d9120fe", 128.98712158203125, dict(
+        makespan=[140.0, 128.98712158203125, 138.17564392089844, 133.77496337890625, 157.99810791015625, 135.10494995117188, 142.32589721679688, 186.88421630859375],
+        comp_max=[137.41880798339844, 128.98712158203125, 138.17564392089844, 133.77496337890625, 157.99810791015625, 135.10494995117188, 142.32589721679688, 186.88421630859375],
+        comm_max=[140.0, 127.20000457763672, 115.5999984741211, 104.4000015258789, 73.5999984741211, 71.5999984741211, 51.60000228881836, 43.20000076293945],
+        moved=[59, 77, 61, 75, 72, 63, 60, 61])),
+}
+
+
+@pytest.mark.parametrize("mode,hetero", list(_TRAJECTORIES),
+                         ids=[f"{m}-{'speed' if h else 'uniform'}"
+                              for m, h in _TRAJECTORIES])
+def test_refine_trajectory_is_pinned(mode, hetero):
+    """Each round prices moves from the scoring that accepted its input, and
+    the heavy arcs are found once: the same work as scoring every partition
+    afresh, so the trajectory repeats bit for bit."""
+    import hashlib
+    digest, best_m, fields = _TRAJECTORIES[(mode, hetero)]
+    bp, bm, stats = refine(*_trajectory_case(mode, hetero))
+    assert hashlib.sha256(bp.astype(np.int32).tobytes()).hexdigest()[:16] == digest
+    assert np.float32(bm) == np.float32(best_m)
+    for name, want in fields.items():
+        got = getattr(stats, name)
+        np.testing.assert_array_equal(got, np.asarray(want, dtype=got.dtype),
+                                      err_msg=name)
+
+
+def test_sparse_round_scatters_once_into_quotient():
+    """The sparse scan body holds one scatter into the k^2 quotient cells
+    (the scoring of the moved partition) and no scatter-max (the heavy-arc
+    search runs once, outside the scan)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import refine as refine_mod
+    g, topo, part0, cfg = _trajectory_case("sparse", False)
+    k = topo.k
+    closed = jax.make_jaxpr(functools.partial(
+        refine_mod._refine_core, k=k, rounds=cfg.rounds, dense=False,
+        damping=cfg.damping, temp0=cfg.temp0, temp_min=cfg.temp_min,
+        anneal=cfg.anneal, inflow_slack=cfg.inflow_slack))(
+        jnp.asarray(part0, jnp.int32), jnp.asarray(g.senders),
+        jnp.asarray(g.receivers), jnp.asarray(g.edge_weight),
+        jnp.asarray(g.node_weight), jnp.asarray(g.offsets[:-1], jnp.int32),
+        jnp.asarray(g.degrees(), jnp.int32), jnp.asarray(topo.subtree),
+        jnp.asarray(topo.F_l), jax.random.PRNGKey(0))
+
+    def eqns(jaxpr):
+        for e in jaxpr.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from eqns(sub)
+
+    scans = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    body = [e for e in eqns(scans[0].params["jaxpr"].jaxpr)
+            if e.primitive.name.startswith("scatter")]
+    names = sorted(e.primitive.name for e in body)
+    quotient = [e for e in body
+                if e.primitive.name == "scatter-add"
+                and e.invars[0].aval.shape == (k * k,)]
+    assert len(quotient) == 1, names
+    assert "scatter-max" not in names, names
 
 
 def test_vertex_weighted_partitioning():
