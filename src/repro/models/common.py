@@ -7,6 +7,7 @@ Parameters are plain nested dicts of jnp arrays; every init function returns
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Dict, Optional
 
@@ -43,9 +44,59 @@ def swiglu(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
-def rope_freqs(d_head: int, max_len: int, theta: float = 1e4) -> jnp.ndarray:
-    """[max_len, d_head // 2] angles."""
-    inv = 1.0 / (theta ** (np.arange(0, d_head, 2) / d_head))
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling as DeepSeek-V2 configures it (``rope_scaling``
+    with ``type: yarn``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def inv_freq(self, d_head: int, theta: float) -> np.ndarray:
+        """Blend of interpolated (``/ factor``) and original frequencies,
+        the original kept above ``beta_fast`` rotations over the original
+        context and the interpolated below ``beta_slow``."""
+        extra = 1.0 / (theta ** (np.arange(0, d_head, 2) / d_head))
+        inter = extra / self.factor
+
+        def corr(rotations):
+            return d_head * np.log(self.original_max_position
+                                   / (rotations * 2 * np.pi)) \
+                / (2 * np.log(theta))
+        low = max(int(np.floor(corr(self.beta_fast))), 0)
+        high = min(int(np.ceil(corr(self.beta_slow))), d_head - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(d_head // 2) - low) / (high - low), 0, 1)
+        return inter * ramp + extra * (1.0 - ramp)
+
+    def softmax_gain(self) -> float:
+        """What multiplies ``1/sqrt(qk_head_dim)``: ``mscale(factor,
+        mscale_all_dim)²``."""
+        m = _yarn_mscale(self.factor, self.mscale_all_dim) \
+            if self.mscale_all_dim else 1.0
+        return m * m
+
+
+def _yarn_mscale(factor: float, m: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * m * float(np.log(factor)) + 1.0
+
+
+def rope_freqs(d_head: int, max_len: int, theta: float = 1e4,
+               yarn: Optional[Yarn] = None) -> jnp.ndarray:
+    """[max_len, d_head // 2] angles; YaRN-scaled frequencies with
+    ``yarn``."""
+    if yarn is None:
+        inv = 1.0 / (theta ** (np.arange(0, d_head, 2) / d_head))
+    else:
+        if _yarn_mscale(yarn.factor, yarn.mscale) != _yarn_mscale(
+                yarn.factor, yarn.mscale_all_dim):
+            raise NotImplementedError("a YaRN cos/sin gain other than 1 "
+                                      "(mscale != mscale_all_dim)")
+        inv = yarn.inv_freq(d_head, theta)
     t = np.arange(max_len)
     return jnp.asarray(np.outer(t, inv), dtype=jnp.float32)
 

@@ -8,9 +8,14 @@ Faithful pieces per the assigned configs:
     shared single-head RoPE key; decode runs the *absorbed* path — the cache
     stores only ``[c_kv | k_rope]`` and ``W_uk``/``W_uv`` are folded into the
     query/output projections, so per-token KV bytes are rank-sized.
-  * MoE (DeepSeek-V2): shared experts + routed top-k with sort-based
-    capacity dispatch (no [T, E] cumsum tensors — O(T·k) memory), optional
-    aux load-balance loss. First ``n_dense_layers`` layers use a dense FFN.
+  * MoE (DeepSeek-V2): shared experts + routed top-k. Training runs
+    sort-based capacity dispatch (no [T, E] cumsum tensors — O(T·k)
+    memory) with an optional aux load-balance loss; decode runs the
+    dropless expert-share layer (``expert_share_ffn``), which holds
+    ``experts_held`` of the routed experts, routes over all of them and
+    returns its share's part plus the shared experts. First
+    ``n_dense_layers`` layers use a dense FFN.
+  * YaRN rope scaling (``yarn``) with its softmax ``mscale²``.
 
 Distribution: parameters/activations are annotated with *logical* axes via
 ``repro.dist.sharding.Rules``; the same code lowers on 1 device, the 256-chip
@@ -58,6 +63,11 @@ class TransformerConfig:
     n_dense_layers: int = 0               # leading dense-FFN layers
     capacity_factor: float = 1.5
     aux_loss_coef: float = 0.003
+    norm_topk_prob: bool = True           # renormalise the top-k weights
+    routed_scaling_factor: float = 1.0    # else scale them by this
+    # (first, count) of the routed experts this chip holds (expert
+    # parallelism); None = all of them
+    experts_held: Optional[Tuple[int, int]] = None
     # --- MLA (deepseek-v2) ---
     mla: bool = False
     kv_lora_rank: int = 0
@@ -65,6 +75,7 @@ class TransformerConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    yarn: Optional[common.Yarn] = None    # YaRN rope scaling
     # --- numerics / runtime ---
     dtype: Any = jnp.bfloat16
     remat: bool = True
@@ -85,6 +96,24 @@ class TransformerConfig:
     def qk_head_dim(self) -> int:
         return (self.qk_nope_head_dim + self.qk_rope_head_dim
                 if self.mla else self.head_dim)
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        return self.experts_held or (0, self.n_experts)
+
+    @property
+    def mla_scale(self) -> float:
+        """Softmax scale of MLA scores: ``qk_head_dim^-1/2``, times YaRN's
+        ``mscale²`` when the rope is scaled."""
+        gain = self.yarn.softmax_gain() if self.yarn else 1.0
+        return gain / float(np.sqrt(self.qk_head_dim))
+
+    def angles(self, max_len: int) -> jnp.ndarray:
+        """Rope angles [max_len, rotated dims // 2]."""
+        return rope_freqs(self.qk_rope_head_dim if self.mla
+                          else self.head_dim, max_len, self.rope_theta,
+                          self.yarn)
 
     def n_params(self) -> int:
         """Total parameter count (for 6ND model-FLOPs accounting)."""
@@ -174,8 +203,8 @@ def _ffn_init(key, cfg: TransformerConfig, rules: Rules, moe_layer: bool):
     p: Params = {}
     s: Params = {}
     if moe_layer:
-        e, f = cfg.n_experts, cfg.d_ff_expert
-        p["router"] = dense_init(ks[0], d, e, jnp.float32)
+        e, f = cfg.held[1], cfg.d_ff_expert
+        p["router"] = dense_init(ks[0], d, cfg.n_experts, jnp.float32)
         p["w_gate"] = (jax.random.normal(ks[1], (e, d, f))
                        / np.sqrt(d)).astype(cfg.dtype)
         p["w_up"] = (jax.random.normal(ks[2], (e, d, f))
@@ -256,6 +285,57 @@ class MoEStats(NamedTuple):
     dropped_frac: jnp.ndarray
 
 
+def route(router: jnp.ndarray, x: jnp.ndarray, cfg: TransformerConfig
+          ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Softmax routing over all ``n_experts`` in float32, greedy top-k:
+    ``(probs [T, E], weights [T, k], experts [T, k])``. The weights are
+    renormalised with ``norm_topk_prob``, else scaled by
+    ``routed_scaling_factor`` (DeepSeek-V2's gate)."""
+    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    elif cfg.routed_scaling_factor != 1.0:
+        top_p = top_p * cfg.routed_scaling_factor
+    return probs, top_p, top_i
+
+
+def expert_share_ffn(p: Params, x: jnp.ndarray, cfg: TransformerConfig,
+                     active: Optional[jnp.ndarray] = None
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Dropless routed experts of the share held here, plus the shared
+    experts: x [T, D] -> (y [T, D], load [held] int32).
+
+    Tokens route over all ``n_experts`` (:func:`route`); this layer adds
+    ``sum over e in top-k and held of w_e * SwiGLU_e(x)`` and the shared
+    experts, and what experts held elsewhere would add is left to their
+    chips. Every held expert runs on every token of the batch, weighted
+    by the token's routing weight for it (0 where not routed), so no
+    token-expert pair is ever dropped and a token's output does not
+    depend on its batch-mates. The held experts' weights are read once a
+    step either way; at a chip's share of an expert-parallel deployment
+    (8 of 64 experts) the batch's extra FLOPs cost less than that read.
+    ``load`` counts the pairs of ``active`` tokens (all by default) that
+    land on each held expert.
+    """
+    first, count = cfg.held
+    _, top_p, top_i = route(p["router"], x, cfg)
+    hit = (top_i[:, :, None] - first) == jnp.arange(count)   # [T, k, e]
+    w = jnp.where(hit, top_p[:, :, None], 0.0).sum(1)        # [T, e]
+    h = jax.nn.silu(jnp.einsum("td,edf->etf", x, p["w_gate"])) * \
+        jnp.einsum("td,edf->etf", x, p["w_up"])
+    out = jnp.einsum("etf,efd->etd", h, p["w_down"])
+    # the weighting and the sum over held experts stay float32 elementwise
+    # (an f32 matmul would round the weights to bfloat16 on the TPU)
+    y = (w.T[:, :, None] * out.astype(jnp.float32)).sum(0).astype(x.dtype)
+    if cfg.n_shared:
+        y = y + common.swiglu(x, p["ws_gate"], p["ws_up"], p["ws_down"])
+    if active is not None:
+        hit = hit & active[:, None, None]
+    return y, hit.sum(axis=(0, 1), dtype=jnp.int32)
+
+
 def _moe_routed_shardmap(p: Params, x: jnp.ndarray, cfg: TransformerConfig,
                          rules: Rules, mesh) -> Tuple[jnp.ndarray, MoEStats]:
     """Expert-parallel routed experts under shard_map.
@@ -285,10 +365,7 @@ def _moe_routed_shardmap(p: Params, x: jnp.ndarray, cfg: TransformerConfig,
 
     def body(x_l, router, wg, wu, wd):
         idx = jax.lax.axis_index(ep)
-        logits = x_l.astype(jnp.float32) @ router           # [t_l, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_i = jax.lax.top_k(probs, k)
-        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+        probs, top_p, top_i = route(router, x_l, cfg)
         flat_e = top_i.reshape(-1).astype(jnp.int32)
         order = jnp.argsort(flat_e)
         sorted_e = flat_e[order]
@@ -342,8 +419,12 @@ def moe_ffn(p: Params, x: jnp.ndarray, cfg: TransformerConfig,
     Dispatch is sort-based: token-expert pairs are sorted by expert id, the
     within-expert position is ``arange - start(expert)``, and pairs beyond
     the per-expert capacity are dropped (classic capacity-factor semantics)
-    — no [T, E] position tensors are ever built.
+    — no [T, E] position tensors are ever built. Training's dispatch:
+    it holds every expert (decode uses :func:`expert_share_ffn`).
     """
+    if cfg.held != (0, cfg.n_experts):
+        raise ValueError("capacity dispatch holds every routed expert; "
+                         f"this config holds {cfg.held}")
     if cfg.ep_shard_map:
         mesh = _ambient_mesh()
         if mesh is not None and "model" in mesh.axis_names \
@@ -359,10 +440,7 @@ def moe_ffn(p: Params, x: jnp.ndarray, cfg: TransformerConfig,
     cap = int(np.ceil(cfg.capacity_factor * t * k / e))
     cap = max(8, ((cap + 7) // 8) * 8)
 
-    logits = x.astype(jnp.float32) @ p["router"]
-    probs = jax.nn.softmax(logits, axis=-1)              # [T, E]
-    top_p, top_i = jax.lax.top_k(probs, k)               # [T, k]
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    probs, top_p, top_i = route(p["router"], x, cfg)    # [T, E], [T, k]
 
     flat_e = top_i.reshape(-1).astype(jnp.int32)          # [T*k]
     order = jnp.argsort(flat_e)
@@ -457,6 +535,8 @@ def mla_attention(p: Params, x: jnp.ndarray, cfg: TransformerConfig,
     v = (c_kv @ p["w_uv"]).reshape(b, s, h, dv)
 
     q_cat = jnp.concatenate([q_nope, q_rope], axis=-1)
+    if cfg.yarn is not None:       # flash scales by qk_head_dim^-1/2 only
+        q_cat = q_cat * cfg.yarn.softmax_gain()
     k_cat = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, s, h, dr))],
                             axis=-1)
     o = flash_attention(q_cat, k_cat, v, causal=True,
@@ -488,8 +568,7 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
             rules: Rules) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """tokens [B, S] -> (logits [B, S, V], aux_loss scalar)."""
     b, s = tokens.shape
-    angles = rope_freqs(cfg.qk_rope_head_dim if cfg.mla else cfg.head_dim,
-                        s, cfg.rope_theta)
+    angles = cfg.angles(s)
     x = rules.shard(params["embed"][tokens], "batch", "seq", None)
 
     aux_total = jnp.zeros((), jnp.float32)
@@ -620,7 +699,7 @@ def _decode_attn_mla(p, x, layer_cache, pos, cfg: TransformerConfig, rules,
     kr_cache = jax.lax.dynamic_update_slice_in_dim(layer_cache["k_rope"],
                                                    kr_new, pos, 1)
     max_s = c_cache.shape[1]
-    scale = 1.0 / np.sqrt(dn + dr)
+    scale = cfg.mla_scale
     s = (jnp.einsum("bhr,bsr->bhs", q_eff, c_cache,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bhd,bsd->bhs", q_rope, kr_cache,
@@ -643,8 +722,7 @@ def decode_step(params: Params, cache: Params, tokens: jnp.ndarray,
     length). Returns (logits [B, V], updated cache)."""
     b = tokens.shape[0]
     max_seq = (cache["c_kv"] if cfg.mla else cache["k"]).shape[2]
-    angles = rope_freqs(cfg.qk_rope_head_dim if cfg.mla else cfg.head_dim,
-                        max_seq, cfg.rope_theta)
+    angles = cfg.angles(max_seq)
     x = rules.shard(params["embed"][tokens], "batch", None, None)
 
     decode_attn = _decode_attn_mla if cfg.mla else _decode_attn_gqa
@@ -662,7 +740,8 @@ def decode_step(params: Params, cache: Params, tokens: jnp.ndarray,
             xc = xc + o
             hn2 = rms_norm(xc, layer_p["ln2"])
             if moe_layer:
-                y, _ = moe_ffn(layer_p["ffn"], hn2.reshape(b, -1), cfg, rules)
+                y, _ = expert_share_ffn(layer_p["ffn"], hn2.reshape(b, -1),
+                                        cfg)
                 y = y.reshape(xc.shape)
             else:
                 y = common.swiglu(hn2, layer_p["ffn"]["w_gate"],

@@ -26,9 +26,17 @@ Spans of the program:
 
 Counters: ``refine.rounds`` and ``refine.rounds_to_best`` (refinement
 rounds run, and those a result needed), ``decode.pages_gathered`` and
-``decode.pages_live`` (KV pages the decode step reads, and those holding
-a position it attends to), ``serve.admitted`` and ``serve.queue_wait_s``
-(requests admitted, and the seconds they waited in the queue).
+``decode.pages_live`` (KV or latent pages the decode step reads, and
+those holding a position it attends to), ``serve.admitted`` and
+``serve.queue_wait_s`` (requests admitted, and the seconds they waited in
+the queue); and for a model whose expert layers hold a share of the
+routed experts (``ServingEngine.step``, from the per-layer load the MLA
+decode step returns and ``serve.pull`` reads with the sampled tokens):
+``moe.pairs_routed`` (token-expert pairs of the active slots, tokens x
+top-k x MoE layers), ``moe.pairs_local`` (those on held experts),
+``moe.pairs_max`` (the busiest held expert's pairs, summed over steps and
+layers) and ``moe.experts_hit`` (held experts with at least one pair,
+summed likewise).
 """
 from __future__ import annotations
 

@@ -11,15 +11,17 @@ pool when the traffic drifts past a threshold.
 
 Modules:
   * ``kv_cache``     — free-list page allocator, per-request page tables,
-                       the pooled K/V arrays, access-count traffic, and
-                       physical page reordering under a placement.
+                       the pooled K/V arrays (or MLA's latent pool),
+                       access-count traffic, and physical page reordering
+                       under a placement.
   * ``scheduler``    — FIFO admit / completion-evict scheduler with
                        page-exhaustion backpressure (pure bookkeeping,
                        JAX-free, so invariants are property-testable).
   * ``paged_decode`` — one batched decode step that reads/writes K/V
-                       through page tables with per-request positions;
-                       logits match ``models.transformer.decode_step``
-                       exactly (the load-bearing equivalence test).
+                       (GQA) or the latent cache (MLA, absorbed) through
+                       page tables with per-request positions; logits
+                       match ``models.transformer.decode_step`` exactly
+                       (the load-bearing equivalence tests).
   * ``engine``       — the stream loop tying the three together, with
                        request-level metrics (TTFT, p50/p99 latency,
                        tokens/s) and the drift re-placement policy.

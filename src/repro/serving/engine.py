@@ -117,11 +117,23 @@ class ServeReport:
 def _jitted_decode(cfg, rules):
     """One compiled paged step per (cfg, rules) — engines share it, so a
     bench spinning up several engines (continuous vs static vs placed)
-    compiles once instead of per engine. The program is named
-    ``jit_paged_decode_step`` in a profile."""
+    compiles once instead of per engine. It takes the cache's pools in
+    ``PagedKVCache.pools`` order and returns the logits, the new pools,
+    and for MLA the expert load. The program is named
+    ``jit_paged_decode_step`` (GQA) or ``jit_paged_decode_step_mla`` in
+    a profile."""
     import jax
 
     from repro.serving import paged_decode
+
+    if cfg.mla:
+        def paged_decode_step_mla(params, latent_pool, page_table, lengths,
+                                  tokens):
+            return paged_decode.paged_decode_step_mla(
+                params, latent_pool, page_table, lengths, tokens, cfg=cfg,
+                rules=rules)
+
+        return jax.jit(paged_decode_step_mla, donate_argnums=(1,))
 
     def paged_decode_step(params, k_pool, v_pool, page_table, lengths,
                           tokens):
@@ -253,14 +265,18 @@ class ServingEngine:
                     "admitted — infeasible request escaped submit()")
             return
         with obs.span("serve.dispatch"):
-            logits, self.cache.k_pool, self.cache.v_pool = self._decode(
-                self.params, self.cache.k_pool, self.cache.v_pool,
+            n_pools = len(self.cache.pools)
+            logits, *out = self._decode(
+                self.params, *self.cache.pools,
                 jnp.asarray(self.cache.page_table), jnp.asarray(lengths),
                 jnp.asarray(tokens))
+            self.cache.pools, expert_load = out[:n_pools], out[n_pools:]
             sampled = self._sample(logits, jnp.asarray(rids),
                                    jnp.asarray(lengths))
         with obs.span("serve.pull"):
             sampled = np.asarray(sampled)
+            if obs.on() and expert_load and expert_load[0].size:
+                self._count_experts(np.asarray(expert_load[0]), len(inputs))
         if obs.on():
             # the step gathers every page-table entry of every slot, and
             # attends to the pages holding positions [0, pos] of each
@@ -283,6 +299,14 @@ class ServingEngine:
         if (ecfg.replace_every > 0
                 and self._step % ecfg.replace_every == 0):
             self._maybe_replace()
+
+    def _count_experts(self, load: np.ndarray, n_active: int) -> None:
+        """Routing counters of one step from its expert load
+        ``[n_moe_layers, held]`` (pairs of the active slots)."""
+        obs.add("moe.pairs_routed", n_active * self.cfg.top_k * load.shape[0])
+        obs.add("moe.pairs_local", int(load.sum()))
+        obs.add("moe.pairs_max", int(load.max(axis=1).sum()))
+        obs.add("moe.experts_hit", int((load > 0).sum()))
 
     def run(self) -> ServeReport:
         """Drain the queue; return the stream report."""

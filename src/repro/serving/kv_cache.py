@@ -21,9 +21,18 @@ multi-device pool would shard on its page axis).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+
+LANES = 128     # the TPU's vector width: a latent entry is padded to it
+
+
+def latent_width(cfg) -> int:
+    """Elements of one token's latent entry (``kv_lora_rank`` +
+    ``qk_rope_head_dim``) padded to a multiple of :data:`LANES`."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // LANES) * LANES
 
 
 class PagePoolExhausted(RuntimeError):
@@ -135,13 +144,18 @@ class PagePlacement:
 
 
 class PagedKVCache:
-    """Page-table bookkeeping plus (optionally) the pooled K/V arrays.
+    """Page-table bookkeeping plus (optionally) the pooled cache arrays.
 
     ``cfg=None`` builds the bookkeeping-only cache the scheduler property
     tests drive — no JAX import, no pools. With a ``TransformerConfig``
-    the pools are ``[n_layers, n_pages + 1, page_size, kh, dh]`` (GQA
-    layout; MLA's rank-compressed cache has no per-head pages and is not
-    served by this path yet).
+    the page shape comes from the model: GQA keeps K and V pools
+    ``[n_layers, n_pages + 1, page_size, kh, dh]`` (``k_pool``,
+    ``v_pool``); MLA keeps one latent pool ``[n_layers * (n_pages + 1),
+    page_size, latent_width(cfg)]`` (``latent_pool``: page ``p`` of layer
+    ``l`` is ``[l * (n_pages + 1) + p]``, a token's ``c_kv`` then its
+    shared rope key, padded to the TPU's 128 lanes; ``paged_decode`` says
+    why this shape). Allocation, page tables and the access record are
+    the same for both.
     """
 
     def __init__(self, n_pages: int, page_size: int, n_slots: int,
@@ -166,17 +180,44 @@ class PagedKVCache:
         self.cfg = cfg
         self.k_pool = None
         self.v_pool = None
+        self.latent_pool = None
         if cfg is not None:
             import jax.numpy as jnp
             if cfg.mla:
-                raise NotImplementedError(
-                    "paged serving covers the GQA cache layout; MLA's "
-                    "rank-compressed cache needs its own page shape "
-                    "(ROADMAP: serving follow-up)")
-            shape = (cfg.n_layers, n_pages + 1, page_size, cfg.n_kv_heads,
-                     cfg.head_dim)
-            self.k_pool = jnp.zeros(shape, cfg.dtype)
-            self.v_pool = jnp.zeros(shape, cfg.dtype)
+                self.latent_pool = jnp.zeros(
+                    (cfg.n_layers * (n_pages + 1), page_size,
+                     latent_width(cfg)), cfg.dtype)
+            else:
+                shape = (cfg.n_layers, n_pages + 1, page_size,
+                         cfg.n_kv_heads, cfg.head_dim)
+                self.k_pool = jnp.zeros(shape, cfg.dtype)
+                self.v_pool = jnp.zeros(shape, cfg.dtype)
+
+    @property
+    def pools(self) -> Tuple:
+        """The device pools in the decode step's argument order:
+        ``(latent_pool,)`` for MLA, ``(k_pool, v_pool)`` for GQA, ``()``
+        when there are none."""
+        if self.latent_pool is not None:
+            return (self.latent_pool,)
+        if self.k_pool is not None:
+            return (self.k_pool, self.v_pool)
+        return ()
+
+    @pools.setter
+    def pools(self, new: Sequence) -> None:
+        if self.cfg.mla:
+            (self.latent_pool,) = new
+        else:
+            self.k_pool, self.v_pool = new
+
+    def _by_page(self, pool):
+        """``pool`` with its physical pages on axis 1 (the latent pool
+        stacks every layer's pages on its first axis)."""
+        if self.cfg.mla:
+            return pool.reshape(self.cfg.n_layers, self.n_pages + 1,
+                                *pool.shape[1:])
+        return pool
 
     # -- allocation ------------------------------------------------------
 
@@ -239,9 +280,9 @@ class PagedKVCache:
             self.access_count[idx] = 0.0
             self.traffic[idx, :] = 0.0
             self.traffic[:, idx] = 0.0
-            if self.k_pool is not None:
-                self.k_pool = self.k_pool.at[:, idx].set(0)
-                self.v_pool = self.v_pool.at[:, idx].set(0)
+            if self.pools:
+                self.pools = [self._by_page(p).at[:, idx].set(0)
+                              .reshape(p.shape) for p in self.pools]
 
     # -- measured traffic ------------------------------------------------
 
@@ -297,11 +338,11 @@ class PagedKVCache:
         self.allocator.relabel(perm)
         self.access_count = self.access_count[order]
         self.traffic = self.traffic[np.ix_(order, order)]
-        if self.k_pool is not None:
+        if self.pools:
             import jax.numpy as jnp
             gather = jnp.asarray(np.append(order, self.sentinel))
-            self.k_pool = self.k_pool[:, gather]
-            self.v_pool = self.v_pool[:, gather]
+            self.pools = [self._by_page(p)[:, gather].reshape(p.shape)
+                          for p in self.pools]
         return perm
 
     # -- invariant probes (tests / analysis) -----------------------------

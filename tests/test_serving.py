@@ -212,10 +212,28 @@ def test_placement_permutation_preserves_logits():
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
 
 
-def test_mla_cache_not_paged_yet():
+def test_mla_cache_pages_latent():
+    """MLA's page shape comes from the model: one latent pool of
+    ``c_kv ‖ k_rope`` per token, which placement permutes and a device
+    death zeroes, as the K and V pools are."""
     cfg = configs.get("deepseek-v2-lite-16b").smoke_config()
-    with pytest.raises(NotImplementedError, match="MLA"):
-        PagedKVCache(8, 4, 2, 4, cfg=cfg)
+    cache = PagedKVCache(8, 4, 2, 4, cfg=cfg)
+    assert cache.k_pool is None and cache.v_pool is None
+    n_layers, width = cfg.n_layers, 128       # 16 + 8 padded to 128 lanes
+    shape = (n_layers * 9, 4, width)
+    assert cache.latent_pool.shape == shape
+    assert cache.pools == (cache.latent_pool,)
+    # [l * 9 + p] holds page p of layer l: mark each with p + 1
+    marks = np.tile(np.arange(1, 10), n_layers)[:, None, None]
+    cache.latent_pool = jnp.broadcast_to(jnp.asarray(marks, cfg.dtype),
+                                         shape)
+    perm = cache.apply_placement(np.asarray([1, 0, 1, 0, 1, 0, 1, 0]))
+    by_page = np.asarray(cache.latent_pool).reshape(n_layers, 9, 4, width)
+    assert (by_page[:, perm, 0, 0] == np.arange(1, 9)).all()
+    assert (by_page[:, 8] == 9).all()
+    cache.fail_pages([int(perm[3])])
+    by_page = np.asarray(cache.latent_pool).reshape(n_layers, 9, 4, width)
+    assert not by_page[:, perm[3]].any() and by_page[:, perm[2]].all()
 
 
 # ---------------------------------------------------------------------------
