@@ -3,7 +3,8 @@
 This is the hardware adaptation of the paper's implied refinement loop
 (DESIGN.md §2): classical partitioners refine with priority-queue FM — a
 sequential, pointer-chasing pattern with no TPU analogue. Here every round is
-a few gathers and scatters over the arc list plus small GEMMs over the bins:
+a few gathers and scatters over the edge list (one arc per undirected edge)
+plus small GEMMs over the bins:
 
   1. Price bins and links with the gradient of the annealed soft-max
      potential (softmax weights concentrate on the bottleneck terms), from
@@ -15,16 +16,18 @@ a few gathers and scatters over the arc list plus small GEMMs over the bins:
   3. Every vertex evaluates candidate destination bins against ``pi`` and
      the bin prices, either densely (all k bins, via the ``partition_gain``
      connectivity kernel) or sparsely (one sampled candidate per vertex,
-     O(m) via arc gathers) — the dense mode is used on coarse levels, the
+     O(m) via edge gathers: ``pi`` is symmetric, so one price serves both
+     ends of an edge) — the dense mode is used on coarse levels, the
      sparse mode on multi-million-vertex fine levels.
   4. A damped, inflow-capped subset of positive-gain moves is applied;
      acceptance of the *round* is judged by the true (hard-max) makespan, so
      the smoothing never corrupts the objective — it only prices moves.
-  5. The moved assignment is scored once (one quotient-matrix scatter into
-     ``k^2`` cells): that scoring both judges the round and prices the next.
+  5. The moved assignment is scored once (one quotient-matrix scatter of
+     the edges into ``k^2`` cells, symmetrised): that scoring both judges
+     the round and prices the next.
 
-What depends on the graph alone — each vertex's heaviest arc — is found
-once per call, outside the rounds.
+What depends on the graph alone — the edge list, each vertex's heaviest
+neighbour — is found once per call, outside the rounds.
 
 The whole loop is one ``lax.scan`` under ``jit``; the temperature anneals
 from ``temp0`` toward ``temp_min`` so early rounds spread pressure across
@@ -87,14 +90,21 @@ def price_matrix(g_link: jnp.ndarray, subtree: jnp.ndarray) -> jnp.ndarray:
     return u[:, None] + u[None, :] - 2.0 * cross
 
 
-def _score(part, senders, receivers, edge_weight, node_weight, subtree, F_l,
-           k, speed=None):
+def _quotient(part, eu, ev, ew, k):
+    """``objective.quotient_matrix`` from one arc per edge, ``W_e + W_eᵀ``:
+    half the scatter. Exact where the weights are whole numbers; fractional
+    weights change ``W`` only in rounding."""
+    W_e = objective.quotient_matrix(part, eu, ev, ew, k)
+    return W_e + W_e.T
+
+
+def _score(part, eu, ev, ew, node_weight, subtree, F_l, k, speed=None):
     """(RAW per-bin loads [k], breakdown) of ``part``. The operations of
     ``objective.makespan_tree``; the raw load is kept because pricing and
     the inflow cap take it, and the breakdown's ``comp`` is ``raw / speed``
     exactly as ``comp_loads`` divides."""
     comp = objective.comp_loads(part, node_weight, k)
-    W = objective.quotient_matrix(part, senders, receivers, edge_weight, k)
+    W = _quotient(part, eu, ev, ew, k)
     comm = objective.link_loads_tree(W, subtree)
     comp_n = comp if speed is None else comp / speed
     return comp, objective.makespan_from_parts(comp_n, comm, F_l)
@@ -159,10 +169,10 @@ def _dense_round(part, comp, comm, senders, receivers, edge_weight,
 # Sparse mode: one sampled candidate bin per vertex per round. O(m).
 # ---------------------------------------------------------------------------
 
-def _heavy_arcs(senders, edge_weight, n):
-    """[n] index of each vertex's heaviest outgoing arc (the largest arc
-    index among ties; 0 for a vertex with no arcs). A function of the graph
-    alone, so refinement finds it once and not every round.
+def _heavy_neighbours(senders, receivers, edge_weight, n):
+    """[n] receiver of each vertex's heaviest outgoing arc (the largest arc
+    index among ties; arc 0 for a vertex with no arcs). A function of the
+    graph alone, so refinement finds it once and not every round.
 
     Exact two-pass segment argmax. (A float32 composite key
     ``w * (m+1) + arc`` loses the packed arc index once the arc count nears
@@ -175,50 +185,61 @@ def _heavy_arcs(senders, edge_weight, n):
     seg_max = jax.ops.segment_max(w32, senders, num_segments=n)
     at_max = w32 >= seg_max[senders]          # exact: compares its own max
     arc_ids = jnp.where(at_max, jnp.arange(m, dtype=jnp.int32), -1)
-    return jnp.clip(jax.ops.segment_max(arc_ids, senders, num_segments=n),
-                    0, m - 1)
+    best_arc = jnp.clip(jax.ops.segment_max(arc_ids, senders, num_segments=n),
+                        0, m - 1)
+    return receivers[best_arc]
 
 
-def _sample_candidates(part, receivers, best_arc, offsets_pad, degrees,
+def _sample_candidates(part, receivers, heavy_nbr, offsets_pad, degrees,
                        g_comp, mode, key, n):
-    """Candidate destination bin per vertex.
+    """Candidate destination bin per vertex. Every lookup is n-sized.
 
-    mode 0: bin of the heaviest incident arc ``best_arc`` (strongest pull)
+    mode 0: bin of the heaviest neighbour ``heavy_nbr`` (strongest pull)
     mode 1: bin of a uniformly random incident arc (exploration)
     mode 2: cheapest-priced bin (load escape hatch for bottleneck bins)
     """
     m = receivers.shape[0]
-    nbr_bin = part[receivers].astype(jnp.int32)
-    heavy = nbr_bin[best_arc]
+    heavy = part[heavy_nbr].astype(jnp.int32)
 
     rand_off = (jax.random.uniform(key, (n,)) * jnp.maximum(degrees, 1)).astype(jnp.int32)
     rand_arc = jnp.clip(offsets_pad + rand_off, 0, m - 1)
-    rnd = nbr_bin[rand_arc]
+    rnd = part[receivers[rand_arc]].astype(jnp.int32)
 
     cheap = jnp.argmin(g_comp).astype(jnp.int32)
     cand = jnp.where(mode == 0, heavy, jnp.where(mode == 1, rnd, cheap))
     return jnp.where(degrees > 0, cand, part.astype(jnp.int32)).astype(part.dtype)
 
 
-def _sparse_round(part, comp, comm, senders, receivers, edge_weight,
-                  node_weight, offsets_pad, degrees, best_arc, subtree, F_l, k,
-                  temp, key, mode, damping, inflow_slack, speed=None):
+def _gain_comm(part, cand, pi, eu, ev, ew):
+    """[n] link price each vertex x saves by moving to ``cand[x]``: the sum
+    over its arcs x→y of ``w (pi[part[x], part[y]] - pi[cand[x], part[y]])``,
+    walked over each edge once. ``pi`` is symmetric, so ``pi[a_u, a_v]``
+    prices both ends. u's terms go in first, then v's: on a graph from
+    ``from_edges`` every vertex sums its terms in CSR arc order, as the walk
+    over both arcs did, bit for bit."""
+    a_u = part[eu].astype(jnp.int32)
+    a_v = part[ev].astype(jnp.int32)
+    c_u = cand[eu].astype(jnp.int32)
+    c_v = cand[ev].astype(jnp.int32)
+    cur = pi[a_u, a_v]
+    return (jnp.zeros(part.shape[0], ew.dtype)
+            .at[eu].add(ew * (cur - pi[c_u, a_v]))
+            .at[ev].add(ew * (cur - pi[c_v, a_u])))
+
+
+def _sparse_round(part, comp, comm, eu, ev, ew, receivers, node_weight,
+                  offsets_pad, degrees, heavy_nbr, subtree, F_l, k, temp, key,
+                  mode, damping, inflow_slack, speed=None):
     n = part.shape[0]
     g_comp, g_link = _prices(comp, comm, F_l, temp, speed)
     pi = price_matrix(g_link, subtree)
 
     k_cand, k_move = jax.random.split(key)
-    cand = _sample_candidates(part, receivers, best_arc, offsets_pad,
+    cand = _sample_candidates(part, receivers, heavy_nbr, offsets_pad,
                               degrees, g_comp, mode, k_cand, n)
 
-    a_s = part[senders].astype(jnp.int32)
-    b_r = part[receivers].astype(jnp.int32)
-    c_s = cand[senders].astype(jnp.int32)
-    cur = pi[a_s, b_r]
-    new = pi[c_s, b_r]
-    gain_comm = jax.ops.segment_sum(edge_weight * (cur - new), senders,
-                                    num_segments=n)
-    gain = gain_comm + node_weight * (g_comp[part] - g_comp[cand])
+    gain = (_gain_comm(part, cand, pi, eu, ev, ew)
+            + node_weight * (g_comp[part] - g_comp[cand]))
     return _apply_moves(part, cand, gain, node_weight, comp, k_move, k,
                         damping, inflow_slack, speed)
 
@@ -227,15 +248,15 @@ def _sparse_round(part, comp, comm, senders, receivers, edge_weight,
 # Driver
 # ---------------------------------------------------------------------------
 
-def _refine_core(part0, senders, receivers, edge_weight, node_weight,
-                 offsets_pad, degrees, subtree, F_l, key, speed=None, *,
-                 k, rounds, dense, damping, temp0, temp_min, anneal,
-                 inflow_slack):
-    score = functools.partial(_score, senders=senders, receivers=receivers,
-                              edge_weight=edge_weight, node_weight=node_weight,
-                              subtree=subtree, F_l=F_l, k=k, speed=speed)
-    best_arc = (None if dense
-                else _heavy_arcs(senders, edge_weight, part0.shape[0]))
+def _refine_core(part0, senders, receivers, edge_weight, eu, ev, ew,
+                 node_weight, offsets_pad, degrees, subtree, F_l, key,
+                 speed=None, *, k, rounds, dense, damping, temp0, temp_min,
+                 anneal, inflow_slack):
+    score = functools.partial(_score, eu=eu, ev=ev, ew=ew,
+                              node_weight=node_weight, subtree=subtree,
+                              F_l=F_l, k=k, speed=speed)
+    heavy_nbr = (None if dense else _heavy_neighbours(
+        senders, receivers, edge_weight, part0.shape[0]))
 
     def body(state: RefineState, ridx):
         key, sub = jax.random.split(state.key)
@@ -247,10 +268,9 @@ def _refine_core(part0, senders, receivers, edge_weight, node_weight,
         else:
             mode = ridx % 3
             part, moved = _sparse_round(
-                state.part, state.comp, state.comm, senders, receivers,
-                edge_weight, node_weight, offsets_pad, degrees, best_arc,
-                subtree, F_l, k, state.temp, sub, mode, damping, inflow_slack,
-                speed)
+                state.part, state.comp, state.comm, eu, ev, ew, receivers,
+                node_weight, offsets_pad, degrees, heavy_nbr, subtree, F_l, k,
+                state.temp, sub, mode, damping, inflow_slack, speed)
         # one scoring per round: acceptance, stats and the next round's
         # prices share it
         comp, br = score(part)
@@ -276,17 +296,29 @@ _refine_jit = functools.partial(jax.jit, static_argnames=_STATIC)(_refine_core)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _refine_batch_jit(parts0, senders, receivers, edge_weight, node_weight,
-                      offsets_pad, degrees, subtree, F_l, keys, speed=None,
-                      *, k, rounds, dense, damping, temp0, temp_min, anneal,
-                      inflow_slack):
+def _refine_batch_jit(parts0, senders, receivers, edge_weight, eu, ev, ew,
+                      node_weight, offsets_pad, degrees, subtree, F_l, keys,
+                      speed=None, *, k, rounds, dense, damping, temp0,
+                      temp_min, anneal, inflow_slack):
     def one(p0, key):
-        return _refine_core(p0, senders, receivers, edge_weight, node_weight,
-                            offsets_pad, degrees, subtree, F_l, key, speed,
-                            k=k, rounds=rounds, dense=dense, damping=damping,
-                            temp0=temp0, temp_min=temp_min, anneal=anneal,
-                            inflow_slack=inflow_slack)
+        return _refine_core(p0, senders, receivers, edge_weight, eu, ev, ew,
+                            node_weight, offsets_pad, degrees, subtree, F_l,
+                            key, speed, k=k, rounds=rounds, dense=dense,
+                            damping=damping, temp0=temp0, temp_min=temp_min,
+                            anneal=anneal, inflow_slack=inflow_slack)
     return jax.vmap(one)(parts0, keys)
+
+
+def _graph_arrays(g: Graph):
+    """The graph as ``_refine_core`` takes it: arcs, edges (``Graph.edges``,
+    which raises on an asymmetric arc list), node weights, CSR offsets and
+    degrees."""
+    eu, ev, ew = g.edges()
+    return (jnp.asarray(g.senders), jnp.asarray(g.receivers),
+            jnp.asarray(g.edge_weight), jnp.asarray(eu), jnp.asarray(ev),
+            jnp.asarray(ew), jnp.asarray(g.node_weight),
+            jnp.asarray(g.offsets[:-1], dtype=jnp.int32),
+            jnp.asarray(g.degrees(), dtype=jnp.int32))
 
 
 def refine(g: Graph, topo: TreeTopology, part: np.ndarray,
@@ -307,11 +339,8 @@ def refine(g: Graph, topo: TreeTopology, part: np.ndarray,
              else jnp.asarray(topo.bin_speed, dtype=jnp.float32))
     best_part, best_m, stats = _refine_jit(
         jnp.asarray(part, dtype=jnp.int32),
-        jnp.asarray(g.senders), jnp.asarray(g.receivers),
-        jnp.asarray(g.edge_weight), jnp.asarray(g.node_weight),
-        jnp.asarray(g.offsets[:-1], dtype=jnp.int32),
-        jnp.asarray(g.degrees(), dtype=jnp.int32),
-        jnp.asarray(topo.subtree), jnp.asarray(topo.F_l), key, speed,
+        *_graph_arrays(g), jnp.asarray(topo.subtree), jnp.asarray(topo.F_l),
+        key, speed,
         k=k, rounds=cfg.rounds, dense=bool(dense), damping=cfg.damping,
         temp0=cfg.temp0, temp_min=cfg.temp_min, anneal=cfg.anneal,
         inflow_slack=cfg.inflow_slack)
@@ -342,11 +371,8 @@ def refine_batch(g: Graph, topo: TreeTopology, parts: np.ndarray,
              else jnp.asarray(topo.bin_speed, dtype=jnp.float32))
     best_parts, best_ms, stats = _refine_batch_jit(
         jnp.asarray(parts, dtype=jnp.int32),
-        jnp.asarray(g.senders), jnp.asarray(g.receivers),
-        jnp.asarray(g.edge_weight), jnp.asarray(g.node_weight),
-        jnp.asarray(g.offsets[:-1], dtype=jnp.int32),
-        jnp.asarray(g.degrees(), dtype=jnp.int32),
-        jnp.asarray(topo.subtree), jnp.asarray(topo.F_l), keys, speed,
+        *_graph_arrays(g), jnp.asarray(topo.subtree), jnp.asarray(topo.F_l),
+        keys, speed,
         k=k, rounds=cfg.rounds, dense=bool(dense), damping=cfg.damping,
         temp0=cfg.temp0, temp_min=cfg.temp_min, anneal=cfg.anneal,
         inflow_slack=cfg.inflow_slack)
@@ -355,6 +381,7 @@ def refine_batch(g: Graph, topo: TreeTopology, parts: np.ndarray,
         stats = jax.tree.map(np.asarray, stats)
     if obs.on():
         obs.add("refine.rounds", stats.makespan.size)
+        obs.add("refine.edge_rounds", 0 if dense else stats.makespan.size)
         obs.add("refine.rounds_to_best",
                 int(rounds_to_best(stats.makespan, best_ms).sum()))
     return best_parts, best_ms, stats

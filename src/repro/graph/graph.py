@@ -44,6 +44,17 @@ class Graph:
     def degrees(self) -> np.ndarray:
         return np.diff(self.offsets)
 
+    def edges(self):
+        """``(u, v, w)``: one arc per undirected edge, those with
+        ``senders < receivers``, in CSR order. Raises ``ValueError`` where
+        they are not exactly half the arcs (the invariant above is broken)."""
+        up = self.senders < self.receivers
+        if 2 * int(np.count_nonzero(up)) != self.n_arcs:
+            raise ValueError(
+                f"{int(np.count_nonzero(up))} of {self.n_arcs} arcs run from "
+                "a lower to a higher id; a symmetric arc list has half")
+        return self.senders[up], self.receivers[up], self.edge_weight[up]
+
     def total_node_weight(self) -> float:
         return float(self.node_weight.sum())
 

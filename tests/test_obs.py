@@ -111,7 +111,25 @@ def test_refine_counters_match_the_returned_stats(tmp_path):
     for m, b in zip(stats.makespan, best_ms):
         want += int(np.argmin(m)) + 1 if m.min() <= b else 0
     assert obs.totals() == {"refine.rounds": 16,
-                            "refine.rounds_to_best": want}
+                            "refine.rounds_to_best": want,
+                            "refine.edge_rounds": 0}
+
+
+def test_edge_rounds_count_every_sparse_round(tmp_path):
+    """``refine.edge_rounds`` counts the slot-rounds priced over each edge
+    once: all of a sparse call's, none of a dense call's."""
+    g = grid2d(12, 12)
+    parts0 = np.stack([random_partition(g.n_nodes, TOPO.k, g.node_weight,
+                                        seed=s) for s in range(3)])
+    dense = RefineConfig(rounds=5)
+    sparse = RefineConfig(rounds=5, dense_threshold=0)
+    for cfg in (dense, sparse):
+        refine_batch(g, TOPO, parts0, cfg)             # compile
+    with traced(tmp_path):
+        refine_batch(g, TOPO, parts0, dense)
+        refine_batch(g, TOPO, parts0, sparse)
+    t = obs.totals()
+    assert t["refine.rounds"] == 30 and t["refine.edge_rounds"] == 15
 
 
 def test_partition_spans_nest(tmp_path):

@@ -98,9 +98,9 @@ def test_refine_batch_slot0_matches_refine():
 
 def test_sampled_heavy_arc_is_exact():
     """The sparse-mode candidate sampler must pick the bin of the true
-    heaviest incident arc (two-pass segment argmax in ``_heavy_arcs``, found
-    once per refinement; the old float32 composite key broke down on large
-    arc counts)."""
+    heaviest incident arc (two-pass segment argmax in ``_heavy_neighbours``,
+    found once per refinement; the old float32 composite key broke down on
+    large arc counts)."""
     import jax
     import jax.numpy as jnp
     from repro.core import refine as refine_mod
@@ -108,10 +108,11 @@ def test_sampled_heavy_arc_is_exact():
     g = rmat(50, 200, seed=7)
     k = 4
     part = rng.integers(0, k, g.n_nodes).astype(np.int32)
-    best_arc = refine_mod._heavy_arcs(jnp.asarray(g.senders),
-                                      jnp.asarray(g.edge_weight), g.n_nodes)
+    heavy_nbr = refine_mod._heavy_neighbours(
+        jnp.asarray(g.senders), jnp.asarray(g.receivers),
+        jnp.asarray(g.edge_weight), g.n_nodes)
     cand = refine_mod._sample_candidates(
-        jnp.asarray(part), jnp.asarray(g.receivers), best_arc,
+        jnp.asarray(part), jnp.asarray(g.receivers), heavy_nbr,
         jnp.asarray(g.offsets[:-1], jnp.int32),
         jnp.asarray(g.degrees(), jnp.int32), jnp.zeros(k), 0,
         jax.random.PRNGKey(0), g.n_nodes)
@@ -193,41 +194,149 @@ def test_refine_trajectory_is_pinned(mode, hetero):
                                       err_msg=name)
 
 
-def test_sparse_round_scatters_once_into_quotient():
-    """The sparse scan body holds one scatter into the k^2 quotient cells
-    (the scoring of the moved partition) and no scatter-max (the heavy-arc
-    search runs once, outside the scan)."""
+def _eqns(jaxpr):
+    import jax
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+def _sparse_scan_body():
+    """(graph, k, every equation of the sparse refinement scan's body) of
+    the pinned sparse case."""
     import jax
     import jax.numpy as jnp
     from repro.core import refine as refine_mod
     g, topo, part0, cfg = _trajectory_case("sparse", False)
-    k = topo.k
     closed = jax.make_jaxpr(functools.partial(
-        refine_mod._refine_core, k=k, rounds=cfg.rounds, dense=False,
+        refine_mod._refine_core, k=topo.k, rounds=cfg.rounds, dense=False,
         damping=cfg.damping, temp0=cfg.temp0, temp_min=cfg.temp_min,
         anneal=cfg.anneal, inflow_slack=cfg.inflow_slack))(
-        jnp.asarray(part0, jnp.int32), jnp.asarray(g.senders),
-        jnp.asarray(g.receivers), jnp.asarray(g.edge_weight),
-        jnp.asarray(g.node_weight), jnp.asarray(g.offsets[:-1], jnp.int32),
-        jnp.asarray(g.degrees(), jnp.int32), jnp.asarray(topo.subtree),
-        jnp.asarray(topo.F_l), jax.random.PRNGKey(0))
-
-    def eqns(jaxpr):
-        for e in jaxpr.eqns:
-            yield e
-            for sub in jax.core.jaxprs_in_params(e.params):
-                yield from eqns(sub)
-
+        jnp.asarray(part0, jnp.int32), *refine_mod._graph_arrays(g),
+        jnp.asarray(topo.subtree), jnp.asarray(topo.F_l),
+        jax.random.PRNGKey(0))
     scans = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
     assert len(scans) == 1
-    body = [e for e in eqns(scans[0].params["jaxpr"].jaxpr)
-            if e.primitive.name.startswith("scatter")]
+    return g, topo.k, list(_eqns(scans[0].params["jaxpr"].jaxpr))
+
+
+def test_sparse_round_scatters_once_into_quotient():
+    """The sparse scan body holds one scatter into the k^2 quotient cells
+    (the scoring of the moved partition) and no scatter-max (the heavy-arc
+    search runs once, outside the scan)."""
+    _, k, eqns = _sparse_scan_body()
+    body = [e for e in eqns if e.primitive.name.startswith("scatter")]
     names = sorted(e.primitive.name for e in body)
     quotient = [e for e in body
                 if e.primitive.name == "scatter-add"
                 and e.invars[0].aval.shape == (k * k,)]
     assert len(quotient) == 1, names
     assert "scatter-max" not in names, names
+
+
+def test_sparse_round_walks_each_edge_once():
+    """No gather or scatter of the sparse scan body walks m indices (one
+    per arc). It walks m/2, one per edge: the bins of both ends of the edge
+    under the partition and the candidates (4), the three prices, the bins
+    of the moved partition for the quotient (2), and three scatter-adds:
+    the gain at each end and the one k^2 quotient."""
+    g, k, eqns = _sparse_scan_body()
+    m = g.n_arcs
+
+    def n_indices(e):
+        return e.invars[1].aval.shape[0]
+
+    walks = [e for e in eqns if e.primitive.name == "gather"
+             or e.primitive.name.startswith("scatter")]
+    assert all(n_indices(e) != m for e in walks)
+    edge = [e.primitive.name for e in walks if n_indices(e) == m // 2]
+    assert sorted(edge) == ["gather"] * 9 + ["scatter-add"] * 3, edge
+    quotient = [e for e in walks if e.primitive.name == "scatter-add"
+                and e.invars[0].aval.shape == (k * k,)]
+    assert len(quotient) == 1 and n_indices(quotient[0]) == m // 2
+
+
+def _edge_view_graphs():
+    return {"rmat": rmat(1000, 6000, seed=11),
+            "grid": grid2d(20, 30),
+            "vertex-weighted": weighted_nodes(rmat(300, 1500, seed=4),
+                                              seed=4, lo=0.2, hi=5.0)}
+
+
+@pytest.mark.parametrize("name", list(_edge_view_graphs()))
+def test_edge_quotient_equals_arc_quotient(name):
+    """``W_e + W_eᵀ`` over one arc per edge is the quotient over both arcs,
+    exactly, for whole-number edge weights."""
+    import jax.numpy as jnp
+    from repro.core import objective
+    from repro.core import refine as refine_mod
+    g = _edge_view_graphs()[name]
+    k = 64
+    part = jnp.asarray(np.random.default_rng(1).integers(0, k, g.n_nodes),
+                       jnp.int32)
+    eu, ev, ew = (jnp.asarray(x) for x in g.edges())
+    want = objective.quotient_matrix(part, jnp.asarray(g.senders),
+                                     jnp.asarray(g.receivers),
+                                     jnp.asarray(g.edge_weight), k)
+    np.testing.assert_array_equal(
+        np.asarray(refine_mod._quotient(part, eu, ev, ew, k)),
+        np.asarray(want))
+
+
+@pytest.mark.parametrize("name", ["rmat", "grid", "grid-weighted"])
+def test_edge_gain_equals_arc_gain(name):
+    """The edge-once link gain equals the sum over both arcs of every edge,
+    bit for bit, on graphs from ``from_edges`` (fractional weights too)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import refine as refine_mod
+    g = {"rmat": rmat(1000, 6000, seed=11), "grid": grid2d(20, 30),
+         "grid-weighted": grid2d(20, 30, seed=2, weighted=True)}[name]
+    topo = balanced_tree((16, 16), level_cost=(0.04, 0.01))
+    rng = np.random.default_rng(2)
+    part = jnp.asarray(rng.integers(0, topo.k, g.n_nodes), jnp.int32)
+    cand = jnp.asarray(rng.integers(0, topo.k, g.n_nodes), jnp.int32)
+    g_link = jax.random.uniform(jax.random.PRNGKey(3), (topo.subtree.shape[0],))
+    pi = refine_mod.price_matrix(g_link, jnp.asarray(topo.subtree))
+    s, r = jnp.asarray(g.senders), jnp.asarray(g.receivers)
+    w = jnp.asarray(g.edge_weight)
+    want = jax.ops.segment_sum(w * (pi[part[s], part[r]] - pi[cand[s], part[r]]),
+                               s, num_segments=g.n_nodes)
+    eu, ev, ew = (jnp.asarray(x) for x in g.edges())
+    np.testing.assert_array_equal(
+        np.asarray(refine_mod._gain_comm(part, cand, pi, eu, ev, ew)),
+        np.asarray(want))
+
+
+@pytest.mark.parametrize("shape", [(16, 16), (2, 4), (4, 4, 4)])
+def test_price_matrix_is_symmetric(shape):
+    """``pi[a, b]`` and ``pi[b, a]`` are the same float: the edge-once round
+    prices both ends of an edge with one gather."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import refine as refine_mod
+    topo = balanced_tree(shape)
+    g_link = jax.random.uniform(jax.random.PRNGKey(4), (topo.subtree.shape[0],))
+    pi = np.asarray(refine_mod.price_matrix(g_link, jnp.asarray(topo.subtree)))
+    np.testing.assert_array_equal(pi, pi.T)
+
+
+def test_asymmetric_arc_list_raises():
+    """A graph that lists one arc of an edge breaks the symmetric-arc
+    invariant that the edge-once round rests on: refinement refuses it."""
+    from repro.graph.graph import Graph
+    g = grid2d(4, 4)
+    up = g.senders < g.receivers
+    half = Graph(g.n_nodes, g.senders[up], g.receivers[up],
+                 g.edge_weight[up], g.node_weight,
+                 np.concatenate([[0], np.cumsum(np.bincount(
+                     g.senders[up], minlength=g.n_nodes))]))
+    with pytest.raises(ValueError, match="symmetric"):
+        half.edges()
+    part = baselines.random_partition(g.n_nodes, 4, seed=0)
+    with pytest.raises(ValueError, match="symmetric"):
+        refine(half, flat_topology(4), part, RefineConfig(rounds=2))
 
 
 def test_vertex_weighted_partitioning():
