@@ -1,11 +1,10 @@
 #!/usr/bin/env bash
 # Tier-1 gate — the exact command CI runs (.github/workflows/ci.yml).
 # Usage: scripts/ci.sh [extra pytest args]
-#        BENCH_SMOKE=1 scripts/ci.sh   # additionally run the benchmark
-#                                      # smoke tier: every benchmarks/
-#                                      # bench_*.py at tiny sizes —
-#                                      # timings are informational,
-#                                      # exceptions fail the gate.
+#        BENCH_SMOKE=1 scripts/ci.sh   # additionally run the launcher
+#                                      # smokes: quickstart, dry-run,
+#                                      # serving, chaos serving and
+#                                      # training, embed, device V-cycle.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
@@ -113,21 +112,4 @@ assert m.bottleneck <= ident + 1e-6, (m.bottleneck, ident)
 print(f"[CI] device V-cycle OK: makespan={res.makespan:.1f}, "
       f"mapped bottleneck={m.bottleneck:.2f} (identity {ident:.2f})")
 PYEOF
-  echo "== benchmark smoke tier (REPRO_BENCH_TINY=1) =="
-  for b in benchmarks/bench_*.py; do
-    mod="benchmarks.$(basename "$b" .py)"
-    echo "-- $mod"
-    REPRO_BENCH_TINY=1 python -c "import importlib; importlib.import_module('$mod').run()"
-  done
-  echo "== bench regression gate (scripts/bench_compare.py) =="
-  for base in benchmarks/baselines/BENCH_*.json; do
-    [[ -e "$base" ]] || continue
-    cur="$(basename "$base")"
-    if [[ -f "$cur" ]]; then
-      echo "-- $cur vs $base"
-      python scripts/bench_compare.py "$base" "$cur"
-    else
-      echo "-- $cur missing (benchmark did not emit it)" && exit 1
-    fi
-  done
 fi

@@ -100,7 +100,7 @@ def collective_traffic_matrix(mesh_shape: Sequence[int],
     """Device-pair traffic matrix [D, D] from per-axis collective bytes.
 
     ``axis_bytes[a]`` = bytes each device exchanges along logical axis ``a``
-    per step (from the HLO collective scan in benchmarks/roofline.py). The
+    per step (from the HLO collective scan in ``launch/collectives.py``). The
     ring model charges ``bytes / (size - 1)`` to each of a device's ring
     neighbors along that axis.
     """

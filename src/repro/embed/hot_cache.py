@@ -7,8 +7,8 @@ shard — ``traffic[requester, owner] += row_bytes`` into the same
 ``[D, D]`` symmetric zero-diagonal matrix shape the mapping search scores
 (``shard_lint.lint_traffic`` lawful) — and the replicated baseline's cost
 model (:func:`replicated_update_traffic`: every touched row's gradient
-broadcast to the other ``D - 1`` replicas) is what the bench compares
-against.
+broadcast to the other ``D - 1`` replicas) is what the launcher's
+traffic report and the tests compare against.
 
 Sparse optimizer: rowwise Adagrad (one accumulator scalar per row).
 Chosen over AdamW for the tables because a zero-gradient row is an exact

@@ -15,7 +15,7 @@ by test). Exceptions in the producer propagate to the consumer at the
 failing position; ``close()`` (idempotent, also called by the train
 loop's ``finally``) stops the thread without draining the stream.
 
-``stats()`` exposes the overlap evidence the bench and tests assert on:
+``stats()`` exposes the overlap evidence the launcher and tests assert on:
 ``max_occupancy`` (batches that were ready and waiting — >= 1 means the
 producer genuinely ran ahead) and ``ready_hits`` (consumer arrivals that
 did not block).

@@ -147,8 +147,3 @@ def to_bsr(n_nodes: int, senders: np.ndarray, receivers: np.ndarray,
     return (all_keys // nb).astype(np.int32), (all_keys % nb).astype(np.int32), \
         blocks, nb
 
-
-def bsr_density(block_rows: np.ndarray, n_block_rows: int, n_block_cols: int):
-    """Fraction of the dense block grid that is materialized — the locality
-    metric the partitioner's reordering drives down."""
-    return block_rows.shape[0] / float(n_block_rows * n_block_cols)

@@ -82,9 +82,6 @@ def _parser() -> argparse.ArgumentParser:
     ap.add_argument("--drift-threshold", type=float, default=0.1)
     ap.add_argument("--place-devices", type=int, default=0,
                     help="placement bins (0 = machine/device count)")
-    ap.add_argument("--static-batching", action="store_true",
-                    help="admit only into an idle batch (the baseline "
-                         "the bench compares against)")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write the ServeReport JSON (per-request "
                          "lifecycle + placement epochs)")
@@ -140,8 +137,7 @@ def serve_stream(args) -> None:
     ecfg = EngineConfig(
         n_slots=args.slots, page_size=page, n_pages=n_pages,
         max_pages_per_req=max_pages, temperature=args.temperature,
-        seed=args.seed, static_batching=args.static_batching,
-        replace_every=args.replace_every,
+        seed=args.seed, replace_every=args.replace_every,
         drift_threshold=args.drift_threshold,
         place_devices=args.place_devices, machine=args.machine)
     injector = None

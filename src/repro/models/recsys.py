@@ -9,7 +9,7 @@ Tables are sharded over rows on the flattened ("data", "model") axis; the
 paper's technique enters as *table-shard placement*: rows are permuted by
 the makespan partitioner over the machine tree (co-access edges, access
 frequency as vertex weight) so the hottest device / hottest link during the
-lookup all-to-all is minimized (see benchmarks/bench_recsys_placement.py).
+lookup all-to-all is minimized (``repro.embed.plan_shards``).
 
 Batch dicts:
   train:      user_hist [B, H] int32 (item-id bags, -1 pad),

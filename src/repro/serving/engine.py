@@ -53,7 +53,6 @@ class EngineConfig:
     max_pages_per_req: int = 16    # page-table width per slot
     temperature: float = 0.8       # 0 = greedy
     seed: int = 0                  # sampling PRNG (per-request folded)
-    static_batching: bool = False  # admit only into an idle batch (bench)
     # -- placement policy --
     replace_every: int = 0         # steps per traffic epoch; 0 = off
     drift_threshold: float = 0.1   # re-place when old/new makespan > 1+thr
@@ -116,7 +115,7 @@ class ServeReport:
 @functools.lru_cache(maxsize=None)
 def _jitted_decode(cfg, rules):
     """One compiled paged step per (cfg, rules) — engines share it, so a
-    bench spinning up several engines (continuous vs static vs placed)
+    process spinning up several engines (a test's clean and chaos runs)
     compiles once instead of per engine. It takes the cache's pools in
     ``PagedKVCache.pools`` order and returns the logits, the new pools,
     and for MLA the expert load. The program is named
@@ -234,8 +233,7 @@ class ServingEngine:
             for ev in self.injector.fire(self._step):
                 self._handle_fault(ev)
         with obs.span("serve.admit"):
-            admitted = self.scheduler.admit(
-                self._step, only_when_idle=ecfg.static_batching)
+            admitted = self.scheduler.admit(self._step)
             if obs.on() and admitted:
                 now = time.perf_counter()
                 obs.add("serve.admitted", len(admitted))

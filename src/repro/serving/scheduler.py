@@ -124,8 +124,7 @@ class Scheduler:
 
     # -- per-step control ------------------------------------------------
 
-    def admit(self, step: int, *, only_when_idle: bool = False
-              ) -> List[Request]:
+    def admit(self, step: int) -> List[Request]:
         """FIFO admission under slot + page backpressure. The head blocks
         the queue when it does not fit (no overtaking) — unless it can
         *never* fit: ``submit`` checked feasibility against the pool size
@@ -134,11 +133,8 @@ class Scheduler:
         here and failed (not blocked on) when it became infeasible. A
         requeued head in backoff (``not_before``) blocks the queue until
         its earliest re-admit step — FIFO is preserved, retries are not
-        overtaken. With ``only_when_idle`` admission waits for an empty
-        batch — the static-batching baseline the bench compares against."""
+        overtaken."""
         admitted: List[Request] = []
-        if only_when_idle and self.active:
-            return admitted
         while self.queue:
             head = self.queue[0]
             if not self.cache.feasible(head.total_tokens):

@@ -62,6 +62,9 @@ def test_shard_plan_invariants_and_coverage():
         np.bincount(plan.row_to_device, minlength=plan.n_devices),
         plan.shard_sizes)
     assert int(plan.shard_sizes.sum()) == plan.n_rows
+    # the permuted table holds each device's rows contiguously
+    assert np.array_equal(np.sort(plan.row_to_device),
+                          plan.row_to_device[plan.order])
 
 
 def test_shard_plan_capacity_proportional_on_hetero_machine():

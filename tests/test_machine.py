@@ -213,6 +213,34 @@ def test_partition_balances_by_capacity_on_heterogeneous_machine():
     assert res.makespan == pytest.approx(m_ref, rel=1e-5)
 
 
+def test_expert_placement_favours_fast_pod_on_heterogeneous_machine():
+    """Expert placement on the two-pod mixed-generation preset puts at
+    least the slow pod's FLOPs on the fast pod, and its capacity-normalized
+    makespan is no worse than a speed-blind random scatter's."""
+    from repro.core import baselines
+    from repro.graph.graph import from_edges
+    spec = MachineSpec.preset("tpu-mixed-32")
+    topo = spec.tree()
+    rng = np.random.default_rng(1)
+    e = 32
+    traffic = rng.uniform(0, 1, (e, e))
+    traffic = traffic + traffic.T
+    np.fill_diagonal(traffic, 0)
+    flops = rng.uniform(0.5, 2.0, e)
+    part, _ = mapping.expert_placement(traffic, flops, topo)
+    iu = np.triu_indices(e, 1)
+    g = from_edges(e, iu[0], iu[1],
+                   (traffic[iu] + traffic.T[iu]).astype(np.float32),
+                   flops.astype(np.float32))
+    fast_bins = np.flatnonzero(np.asarray(topo.bin_speed)
+                               == max(topo.bin_speed))
+    fast = float(flops[np.isin(part, fast_bins)].sum())
+    assert fast >= float(flops.sum()) - fast
+    scatter = rng.permutation(e) % topo.k
+    assert (baselines.score_all(g, topo, part)["makespan"]
+            <= baselines.score_all(g, topo, scatter)["makespan"])
+
+
 # ---------------------------------------------------------------------------
 # Mapping search over machine specs
 # ---------------------------------------------------------------------------
@@ -225,7 +253,7 @@ def _sym_traffic(d, seed=0):
 
 
 @pytest.mark.parametrize("name", ["gpu-superpod", "torus-2d",
-                                  "tpu-mixed-32"])
+                                  "tpu-mixed-32", "tpu_v5e-512"])
 def test_search_on_preset_never_loses_to_identity(name):
     spec = MachineSpec.preset(name)
     d = spec.n_devices

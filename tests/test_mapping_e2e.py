@@ -236,10 +236,15 @@ def test_widened_search_monotone_and_recursive_refinement():
     np.testing.assert_allclose(got, wide.bottleneck, rtol=1e-4)
 
 
-def test_score_device_maps_matches_looped_scorer():
-    topo = _asymmetric_two_level_tree()
-    T = mapping.collective_traffic_matrix((4, 4), {0: 100.0, 1: 7.0})
-    cands, _ = mapping.enumerate_candidates((4, 4), n_random=8, seed=0)
+@pytest.mark.parametrize("shape, tree", [
+    ((4, 4), lambda shape: _asymmetric_two_level_tree()),
+    ((2, 16), mesh_tree),
+    ((4, 4, 4), mesh_tree)], ids=["4x4", "2x16", "4x4x4"])
+def test_score_device_maps_matches_looped_scorer(shape, tree):
+    topo = tree(shape)
+    T = mapping.collective_traffic_matrix(
+        shape, dict(enumerate((100.0, 7.0, 0.5)[:len(shape)])))
+    cands, _ = mapping.enumerate_candidates(shape, n_random=8, seed=0)
     batched = mapping.score_device_maps(T, topo, cands, chunk=16)
     looped = np.asarray([mapping.makespan_of_device_map(T, topo, c)
                          for c in cands])

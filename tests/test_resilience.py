@@ -368,10 +368,13 @@ def test_latest_step_skips_and_sweeps_tmp_dirs(tmp_path):
     assert step == 4 and restored["w"].sum() == 3
 
 
-def test_engine_chaos_matches_clean_run():
+@pytest.mark.parametrize("replace_every", [0, 8])
+def test_engine_chaos_matches_clean_run(replace_every):
     """End-to-end serving acceptance: a real engine stream with one
-    injected leaf death completes every request with survivor tokens
-    bit-identical to the clean run, and reports the recovery."""
+    injected leaf death, with page placement off or on, completes every
+    request with survivor tokens bit-identical to the clean run, reports
+    the recovery, and takes no more extra steps than the replayed tokens
+    plus backoff and admission refill."""
     import jax
     from repro import configs
     from repro.dist.sharding import lm_rules
@@ -390,13 +393,14 @@ def test_engine_chaos_matches_clean_run():
             params, cfg, rules,
             EngineConfig(n_slots=2, page_size=4, n_pages=16,
                          max_pages_per_req=4, temperature=0.8, seed=0,
-                         replace_every=0, place_devices=4),
+                         replace_every=replace_every, place_devices=4),
             injector=injector)
         for prompt, gen in work:
             eng.submit(prompt, gen)
         return eng.run()
 
     clean = serve()
+    assert bool(clean.placements) == (replace_every > 0)
     plan = FaultPlan((FaultEvent(4, "leaf_death", 1),))
     chaos = serve(FaultInjector(plan))
     assert not chaos.failed
@@ -406,4 +410,6 @@ def test_engine_chaos_matches_clean_run():
     assert chaos.recoveries and chaos.recoveries[0]["device"] == 1
     assert chaos.faults[0]["kind"] == "leaf_death"
     assert chaos.tokens_reprefilled >= 0
+    assert chaos.steps <= (clean.steps + chaos.tokens_reprefilled
+                           + 8 * chaos.requests_retried + 8)
     assert "faults:" in chaos.summary()

@@ -344,16 +344,30 @@ def test_engine_completes_all_and_reports():
 
 
 def test_engine_greedy_and_static_batching():
+    """Greedy decoding is scheduling-invariant: 2 and 4 slots sample the
+    same tokens, and more slots never take more steps."""
     cfg, params = _model()
     work = _workload(cfg, n=4, seed=9)
-    cont = _run_engine(cfg, params, work, temperature=0.0)
-    stat = _run_engine(cfg, params, work, temperature=0.0,
-                       static_batching=True)
-    # greedy sampling is scheduling-invariant too
-    assert ({r["rid"]: r["generated"] for r in cont.requests}
-            == {r["rid"]: r["generated"] for r in stat.requests})
-    # continuous batching never takes more decode steps than static
-    assert cont.steps <= stat.steps
+    r2 = _run_engine(cfg, params, work, temperature=0.0, n_slots=2)
+    r4 = _run_engine(cfg, params, work, temperature=0.0, n_slots=4,
+                     n_pages=32)
+    assert ({r["rid"]: r["generated"] for r in r2.requests}
+            == {r["rid"]: r["generated"] for r in r4.requests})
+    assert r4.steps <= r2.steps
+
+
+def test_engine_page_replacement_keeps_sampled_tokens():
+    """Page re-placement is transparent: a stream re-placed every 8 steps
+    over 4 bins samples the same tokens as the stream with placement off."""
+    cfg, params = _model()
+    work = _workload(cfg, n=12, seed=5)
+    plain = _run_engine(cfg, params, work, n_slots=4, n_pages=32)
+    placed = _run_engine(cfg, params, work, n_slots=4, n_pages=32,
+                         replace_every=8, place_devices=4)
+    assert any(p["replaced"] and p["pages_moved"]
+               for p in placed.placements), "no page was ever moved"
+    assert ({r["rid"]: r["generated"] for r in placed.requests}
+            == {r["rid"]: r["generated"] for r in plain.requests})
 
 
 def test_engine_infeasible_request_rejected_at_submit():
